@@ -183,6 +183,9 @@ def run() -> Rows:
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
+    # the child is a virtual-device CPU sweep (its rows say cpu): pin it
+    # there, so it never contends with this process for an attached TPU
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = os.path.join(repo, "src")
     env["PYTHONPATH"] = (

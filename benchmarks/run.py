@@ -88,6 +88,9 @@ def _write_smoke_json(all_rows, module_secs) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv[1:]
     modules = MODULES
     if smoke:

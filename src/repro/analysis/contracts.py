@@ -34,6 +34,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core import pb
+from repro.core.plan import fused_fits, fused_vmem_bytes
 
 
 class ContractError(ValueError):
@@ -160,8 +161,9 @@ def check_stream(
                      ``num_bins * bin_range`` covers ``num_nodes`` (the
                      kernels assert the same; here it fails *before*
                      tracing, with the decision named);
-      fused-fits   — an *analytic* fused decision's accumulator fits
-                     half the fast level at its f_tile (measured/cached
+      fused-fits   — an *analytic* fused decision's kernel footprint
+                     (``plan.fused_fits``) fits the fast level at its
+                     bin range and f_tile (measured/cached
                      fused decisions are evidence-backed and forced ones
                      carry the guarded jnp fallback, so only the model's
                      own claim is policed);
@@ -219,14 +221,16 @@ def check_stream(
     ):
         itemsize = int(np.dtype(getattr(values, "dtype", np.float32)).itemsize)
         eff_cols = decision.f_tile or (vshape[0] if vshape else 0) or 1
-        acc_bytes = num_nodes * eff_cols * itemsize
-        budget = hw.fast_levels[-1] // 2
-        if acc_bytes > budget:
+        geometry = dict(
+            bin_range=decision.bin_range, itemsize=itemsize, f_tile=eff_cols
+        )
+        if not fused_fits(hw, num_nodes, **geometry):
             raise ContractError(
                 "fused-fits",
-                f"analytic fused decision whose accumulator "
-                f"({acc_bytes} B at {eff_cols} resident column(s)) "
-                f"exceeds half the fast level ({budget} B) — "
+                f"analytic fused decision whose kernel footprint "
+                f"({fused_vmem_bytes(num_nodes, **geometry)} B at {eff_cols} "
+                f"resident column(s)) exceeds the fast level "
+                f"({hw.fast_levels[-1]} B) — "
                 f"fused_fits legality is broken (decision {desc})",
             )
     check_cache_key_completeness()

@@ -1,71 +1,19 @@
-"""Version compatibility shims.
+"""Single import sites for jax APIs this repo pins a spelling of.
 
-``shard_map`` moved twice across jax releases:
-
-  * jax < 0.6:  ``jax.experimental.shard_map.shard_map`` with a
-    ``check_rep`` kwarg;
-  * jax >= 0.6: ``jax.shard_map`` with ``check_rep`` renamed to
-    ``check_vma``.
-
-Every module in this repo imports ``shard_map`` from here and may pass
-either spelling of the replication-check kwarg; the shim translates to
-whatever the installed jax accepts.
+The repo is written for the installed jax (0.9). Modules import
+``shard_map`` and ``segment_sum`` from here, so a future move of either
+touches one file; ``cost_analysis`` gives callers a dict even where the
+compiled program reports no costs.
 """
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.6
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Call the installed jax's shard_map, translating kwarg renames."""
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    if "check_rep" in kwargs and "check_rep" not in _PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-try:  # the supported home since jax 0.2.x — jax.ops.segment_sum is a
-    # legacy alias dropped from modern releases
-    from jax.ops import segment_sum as _segment_sum  # type: ignore[attr-defined]
-except ImportError:
-    from jax.lax import segment_sum as _segment_sum  # type: ignore[attr-defined]
-
-
-def segment_sum(data, segment_ids, *, num_segments, indices_are_sorted=False):
-    """``segment_sum`` from wherever the installed jax exposes it.
-
-    ``core/pagerank.py`` used the ``jax.ops.segment_sum`` spelling, which
-    newer jax removes outright; every in-repo caller (and the fig9 SpMM
-    baseline) goes through this shim so the repo keeps one import site to
-    update if the alias moves again.
-    """
-    return _segment_sum(
-        data,
-        segment_ids,
-        num_segments=num_segments,
-        indices_are_sorted=indices_are_sorted,
-    )
+from jax import shard_map
+from jax.ops import segment_sum
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions.
-
-    jax < 0.5 returns a one-element list of dicts (one per module);
-    newer jax returns the dict directly. Either way the caller gets a
-    (possibly empty) dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a (possibly empty) dict."""
+    return compiled.cost_analysis() or {}
 
 
 __all__ = ["shard_map", "cost_analysis", "segment_sum"]

@@ -50,6 +50,7 @@ stream and calling this module — see DESIGN.md §4.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import math
@@ -65,12 +66,18 @@ import numpy as np
 from repro.core import pb
 from repro.core.cobra import hierarchical_binning
 from repro.core.plan import (
+    FUSED_BIN_RANGE,
+    FUSED_BLOCK,
+    FUSED_CAP,
     CobraPlan,
     HardwareModel,
     binning_optimal_num_bins,
     compromise_bin_range,
+    fused_fits,
+    fused_vmem_bytes,
     num_bins_for_range,
 )
+from repro.kernels import resolve_interpret
 
 METHODS = ("sort", "counting", "pallas", "hierarchical")
 
@@ -121,10 +128,9 @@ def execute_binning(
     non-commutative consumers (paper §2) correct under method swaps.
 
     ``interpret=None`` resolves per backend (interpret-mode Pallas off
-    TPU, compiled Mosaic on TPU).
+    TPU, compiled Mosaic on TPU; ``repro.kernels.resolve_interpret``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if method not in METHODS:
         raise ValueError(f"unknown binning method: {method!r} (want one of {METHODS})")
     m = indices.shape[0]
@@ -164,13 +170,46 @@ def execute_binning(
 # ---------------------------------------------------------------------------
 
 
-# The compiled Pallas fused kernel keeps the whole accumulator (plus
-# per-bin C-Buffer scratch) in VMEM; beyond these static bounds the
-# blockwise jnp sweep is the fused realization even on TPU — the same
-# fallback ``decide`` encodes via ``fused_fits`` (DESIGN.md §8.1), here
-# enforced for callers that hardcode method="fused".
-_FUSED_KERNEL_MAX_ACC_BYTES = 32 * 1024 * 1024
-_FUSED_KERNEL_MAX_BINS = 4096
+def fused_realization(
+    out_size: int,
+    values_shape: Tuple[int, ...],
+    dtype,
+    *,
+    interpret: bool,
+    use_pallas: bool = False,
+    bin_range: Optional[int] = None,
+    f_tile: Optional[int] = None,
+    vmem_budget: Optional[int] = None,
+) -> str:
+    """Which realization a ``fused`` reduce of this shape runs; the
+    decision-log entry of every ``fused`` decision names it.
+
+    ``"pallas"`` — the C-Buffer kernel — when it is compiled (a TPU:
+    ``interpret`` False) or asked for (``use_pallas``), the stream is
+    non-empty, values are 32-bit, a row block's F-tile is one the chip
+    can block (all of F, a multiple of 128 lanes, or one column at a time
+    through the scalar kernel), and the kernel's
+    VMEM footprint (``plan.fused_vmem_bytes``) fits ``vmem_budget`` —
+    the fast level of the executor's ``HardwareModel`` (None: the
+    attached device's, ``HardwareModel.attached``), which is also the
+    ``vmem_limit_bytes`` the kernel is compiled with. ``"jnp"`` — the
+    XLA scatter sweep — otherwise. A row block of width 1 runs the scalar
+    kernel.
+    """
+    m = values_shape[0]
+    feat = values_shape[1] if len(values_shape) == 2 else 0
+    if m == 0 or (interpret and not use_pallas):
+        return "jnp"
+    if jnp.dtype(dtype).itemsize != 4 or (len(values_shape) == 2 and feat == 0):
+        return "jnp"
+    budget = vmem_budget or HardwareModel.attached().fast_levels[-1]
+    r = bin_range or FUSED_BIN_RANGE
+    ft = 0
+    if feat > 1:
+        ft = max(1, min(feat, f_tile or feat))
+        if not (interpret or ft in (1, feat) or ft % 128 == 0):
+            return "jnp"
+    return "pallas" if fused_vmem_bytes(out_size, bin_range=r, f_tile=ft) <= budget else "jnp"
 
 
 def _fused_reduce_jnp(
@@ -178,77 +217,45 @@ def _fused_reduce_jnp(
     values: jnp.ndarray,
     out_size: int,
     op: str,
-    block: int = 2048,
     sorted_within: Optional[int] = None,
     in_bounds: bool = False,
 ) -> jnp.ndarray:
-    """Fused fallback off-TPU: one blockwise sweep, each block
-    segment-reduced straight into the dense output (a ``lax.scan`` whose
-    carry IS the accumulator — the jnp rendering of the VMEM-resident
-    accumulator tile in kernels/fused.py). The binned intermediate is
-    never built. ``sorted_within <= 1`` hands XLA the elementwise
-    sortedness fact when the caller actually guarantees it, and
-    ``in_bounds=True`` is the caller's promise that every index lies in
-    ``[0, out_size)`` (a CSR/CSC-derived stream guarantees this by
+    """The jnp realization of the fused reduce: the whole stream reduced
+    straight into the dense output by one XLA scatter (or, for a sorted
+    in-bounds add stream, one sorted segment-sum). The binned
+    intermediate is never built. ``sorted_within <= 1`` hands XLA the
+    elementwise sortedness fact when the caller actually guarantees it,
+    and ``in_bounds=True`` is the caller's promise that every index lies
+    in ``[0, out_size)`` (a CSR/CSC-derived stream guarantees this by
     construction), letting the scatter skip per-update bounds masking.
     The default keeps the drop-out-of-range semantics every other method
     shares.
     """
     vshape = pb.value_block_shape(values)  # raises on unsupported ranks
-    m = indices.shape[0]
     ident = pb.reduce_identity(op, values.dtype)
     out0 = jnp.full((out_size,) + vshape, ident, values.dtype)
-    if m == 0:
+    if indices.shape[0] == 0:
         return out0
     srt = sorted_within is not None and sorted_within <= 1
-    nblocks = -(-m // block)
-    if nblocks == 1:
-        # whole stream is one accumulator sweep: skip the scan (and its
-        # padding) entirely — the common smoke-scale case, and the shape
-        # fig9's per-iteration fused timings measure
-        if op == "add" and srt and in_bounds:
-            # a binned (elementwise-sorted, in-bounds) add stream is a
-            # segmented reduction, not a scatter: XLA's sorted
-            # segment-sum walks the output sequentially — the jnp
-            # rendering of what consuming the binned stream buys
-            # (bit-exact with the scatter form: both accumulate in
-            # stream order within a segment)
-            from repro import compat
+    if op == "add" and srt and in_bounds:
+        # a binned (elementwise-sorted, in-bounds) add stream is a
+        # segmented reduction, not a scatter: XLA's sorted segment-sum
+        # walks the output sequentially — the jnp rendering of what
+        # consuming the binned stream buys (bit-exact with the scatter
+        # form: both accumulate in stream order within a segment)
+        from repro import compat
 
-            return compat.segment_sum(
-                values, indices, num_segments=out_size,
-                # sorted-ok: branch gated on `srt` (caller's sorted_within
-                indices_are_sorted=True,  # claim), checked by REPRO_PB_CHECK
-            ).astype(values.dtype)
-        upd = out0.at[indices]
-        apply = {"add": upd.add, "min": upd.min, "max": upd.max}[op]
-        # the contract checker verifies the promise under REPRO_PB_CHECK:
-        # in-bounds-ok: gated on the caller's explicit in_bounds claim
-        mode = "promise_in_bounds" if in_bounds else "drop"
-        return apply(values, indices_are_sorted=srt, mode=mode)
-    pad = nblocks * block - m
-    # padding indices routed out of bounds and dropped by the scatter
-    idx_p = jnp.pad(indices, (0, pad), constant_values=out_size).reshape(
-        nblocks, block
-    )
-    pad_width = [(0, pad)] + [(0, 0)] * len(vshape)
-    val_p = jnp.pad(values, pad_width, constant_values=0).reshape(
-        (nblocks, block) + vshape
-    )
-
-    def step(out, blk):
-        ib, vb = blk
-        upd = out.at[ib]
-        if op == "add":
-            out = upd.add(vb, mode="drop", indices_are_sorted=srt)
-        elif op == "min":
-            out = upd.min(vb, mode="drop", indices_are_sorted=srt)
-        else:  # max
-            out = upd.max(vb, mode="drop", indices_are_sorted=srt)
-        return out, None
-
-    out, _ = jax.lax.scan(step, out0, (idx_p, val_p))
-    return out
+        return compat.segment_sum(
+            values, indices, num_segments=out_size,
+            # sorted-ok: branch gated on `srt` (caller's sorted_within
+            indices_are_sorted=True,  # claim), checked by REPRO_PB_CHECK
+        ).astype(values.dtype)
+    upd = out0.at[indices]
+    apply = {"add": upd.add, "min": upd.min, "max": upd.max}[op]
+    # the contract checker verifies the promise under REPRO_PB_CHECK:
+    # in-bounds-ok: gated on the caller's explicit in_bounds claim
+    mode = "promise_in_bounds" if in_bounds else "drop"
+    return apply(values, indices_are_sorted=srt, mode=mode)
 
 
 def execute_reduce(
@@ -267,16 +274,19 @@ def execute_reduce(
     sorted_within: Optional[int] = None,
     f_tile: Optional[int] = None,
     in_bounds: bool = False,
+    vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
     """Reduce one (indices, values) stream to a dense (out_size, ...) array.
 
     The traceable core of ``PBExecutor.reduce_stream``. ``method`` is any
     of ``REDUCE_METHODS``: the binning methods run the classic two-phase
     pipeline (``execute_binning`` + ``pb.bin_read_reduce``); ``fused``
-    runs the single-sweep bin-and-accumulate — the Pallas C-Buffer kernel
-    when ``use_pallas`` is set or the backend compiles it (a real TPU:
-    ``interpret`` resolves False), and the blockwise jnp sweep otherwise. Only commutative ops are accepted: order-sensitive
-    consumers must use ``bin_stream`` (DESIGN.md §8).
+    runs the single-sweep bin-and-accumulate in the realization
+    ``fused_realization`` names: the Pallas C-Buffer kernel when the
+    backend compiles it (a TPU: ``interpret`` resolves False) or
+    ``use_pallas`` asks for it, and it fits ``vmem_budget``; the
+    jnp scatter sweep otherwise. Only commutative ops are accepted:
+    order-sensitive consumers must use ``bin_stream`` (DESIGN.md §8).
 
     Row-block ``(m, F)`` values flow through every method (DESIGN.md
     §14): the fused Pallas realization is the feature-tiled row-block
@@ -295,64 +305,40 @@ def execute_reduce(
         raise ValueError(
             f"unknown reduce method: {method!r} (want one of {REDUCE_METHODS})"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if method == "fused":
         vshape = pb.value_block_shape(values)  # raises on unsupported ranks
-        flat = vshape == ()
-        feat = vshape[0] if vshape else 0
-        # the Pallas kernel runs when explicitly requested OR compiled
-        # (non-interpret = a real TPU backend); CPU containers default to
-        # the jnp sweep, which is the faster interpret-mode realization
-        r = bin_range or max(1, min(512, out_size))
-        nb = num_bins or -(-out_size // r)
-        isz = jnp.dtype(values.dtype).itemsize
-        cap = 512
-        if flat:
-            kernel_fits = (
-                nb * r * isz <= _FUSED_KERNEL_MAX_ACC_BYTES
-                and nb <= _FUSED_KERNEL_MAX_BINS
-            )
-        else:
-            # row-block accumulator + per-bin C-Buffer row scratch, both
-            # sized at the F-tile actually resident per sweep
-            ft = max(1, min(feat, f_tile or feat))
-            kernel_fits = feat > 0 and (
-                nb * (r + cap) * ft * isz <= _FUSED_KERNEL_MAX_ACC_BYTES
-                and nb <= _FUSED_KERNEL_MAX_BINS
-            )
-        if (use_pallas or not interpret) and kernel_fits and indices.shape[0] > 0:
-            blk = min(block, 512)
-            if flat:
-                from repro.kernels.fused import cobra_bin_accumulate_pallas
+        r = bin_range or FUSED_BIN_RANGE
+        budget = vmem_budget or HardwareModel.attached().fast_levels[-1]
+        realization = fused_realization(
+            out_size, values.shape, values.dtype, interpret=interpret,
+            use_pallas=use_pallas, bin_range=r, f_tile=f_tile, vmem_budget=budget,
+        )
+        if realization == "pallas":
+            from repro.kernels import fused as fused_kernels
 
-                return cobra_bin_accumulate_pallas(
-                    indices,
-                    values,
-                    num_indices=out_size,
-                    bin_range=r,
-                    num_bins=nb,
-                    op=op,
-                    block=blk,
-                    cap=cap,  # >= blk by construction (kernel asserts)
-                    interpret=interpret,
+            kw = dict(
+                num_indices=out_size, bin_range=r, op=op, block=FUSED_BLOCK,
+                cap=FUSED_CAP, interpret=interpret, vmem_limit_bytes=budget,
+            )
+            if vshape in ((), (1,)):  # a width-1 row block is a scalar lane
+                out = fused_kernels.cobra_bin_accumulate_pallas(
+                    indices, values.reshape(-1), **kw
                 )
-            from repro.kernels.fused import cobra_bin_accumulate_rows_pallas
-
-            return cobra_bin_accumulate_rows_pallas(
-                indices,
-                values,
-                num_indices=out_size,
-                bin_range=r,
-                num_bins=nb,
-                op=op,
-                block=blk,
-                cap=cap,
-                f_tile=ft,
-                interpret=interpret,
+                return out.reshape((out_size,) + vshape)
+            ft = max(1, min(vshape[0], f_tile or vshape[0]))
+            if ft == 1:
+                # the narrowest tile: one scalar sweep per column, for rows
+                # whose 128-lane tile does not fit
+                return jnp.stack([
+                    fused_kernels.cobra_bin_accumulate_pallas(indices, values[:, c], **kw)
+                    for c in range(vshape[0])
+                ], axis=1)
+            return fused_kernels.cobra_bin_accumulate_rows_pallas(
+                indices, values, f_tile=ft, **kw,
             )
         return _fused_reduce_jnp(
-            indices, values, out_size, op, block=block,
+            indices, values, out_size, op,
             sorted_within=sorted_within, in_bounds=in_bounds,
         )
     r = bin_range or max(1, min(512, out_size))
@@ -625,6 +611,37 @@ class _AutotuneCache:
         self._save()
 
 
+class HostStreamStats:
+    """The host share of the streams a traversal hands its executor,
+    summed over calls until ``reset``: seconds spent expanding frontiers
+    and building level streams in host numpy, uploads to the device
+    included; the bytes uploaded; the levels expanded. Reading a level's
+    result back to the host waits on the device, so it is not in
+    ``seconds``."""
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self) -> None:
+        self.seconds = 0.0
+        self.upload_bytes = 0
+        self.levels = 0
+
+    @contextlib.contextmanager
+    def timed(self):
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.seconds += time.perf_counter() - t0
+
+    def upload(self, a: np.ndarray) -> jnp.ndarray:
+        with self.timed():
+            self.upload_bytes += a.nbytes
+            # waited for, so the copy is in ``seconds``
+            return jnp.asarray(a).block_until_ready()
+
+
 # ---------------------------------------------------------------------------
 # The executor.
 # ---------------------------------------------------------------------------
@@ -649,17 +666,41 @@ def _jitted_binning(bin_range, num_bins, method, block, interpret, plan):
 
 @functools.lru_cache(maxsize=256)
 def _jitted_reduce_batched(
-    out_size, bin_range, num_bins, method, op, block, interpret, sorted_within
+    out_size, bin_range, num_bins, method, op, block, interpret, sorted_within,
+    vmem_budget,
 ):
-    """vmap of the reduce core over a leading batch axis. ``fused`` is
-    realized as the blockwise jnp sweep (the one fused rendering that is
-    vmap-safe on every backend); the two-phase methods vmap through
-    ``execute_reduce`` directly."""
+    """The reduce core over a leading batch axis. A ``fused`` reduce
+    whose lane realizes as ``"pallas"`` (``fused_realization`` at
+    ``vmem_budget``) runs the C-Buffer kernel once per lane, since a
+    Pallas grid does not vmap. Every other method, and the jnp sweep,
+    vmap through ``execute_reduce`` / ``_fused_reduce_jnp``.
+
+    Why the kernel, where it fits, and not the vmapped scatter (one v5e,
+    PERF.md §5): per compiled program the kernel costs 0.5-1.1 s of
+    compile and 48 ns per tuple, the scatter 6-11 s and 11 ns. A serving
+    process compiles one program per (lanes, bucket, op), and the
+    scatter's faster sweep repays its compile only past ~2e8 tuples
+    through that program; a cold frontier level sees far fewer."""
+
+    def batched(idx, val):
+        if method == "fused" and fused_realization(
+            out_size, val.shape[1:], val.dtype, interpret=interpret,
+            bin_range=bin_range, vmem_budget=vmem_budget,
+        ) == "pallas":
+            return jnp.stack([
+                execute_reduce(
+                    idx[q], val[q], out_size=out_size, op=op, method="fused",
+                    bin_range=bin_range, interpret=interpret,
+                    vmem_budget=vmem_budget,
+                )
+                for q in range(idx.shape[0])
+            ])
+        return jax.vmap(one)(idx, val)
 
     def one(idx, val):
         if method == "fused":
             return _fused_reduce_jnp(
-                idx, val, out_size, op, block=block, sorted_within=sorted_within
+                idx, val, out_size, op, sorted_within=sorted_within
             )
         return execute_reduce(
             idx,
@@ -675,13 +716,13 @@ def _jitted_reduce_batched(
             sorted_within=sorted_within,
         )
 
-    return jax.jit(jax.vmap(one))
+    return jax.jit(batched)
 
 
 @functools.lru_cache(maxsize=256)
 def _jitted_reduce(
     out_size, bin_range, num_bins, method, op, block, interpret, plan, use_pallas,
-    sorted_within, f_tile=None, in_bounds=False,
+    sorted_within, f_tile=None, in_bounds=False, vmem_budget=None,
 ):
     def f(idx, val):
         return execute_reduce(
@@ -699,6 +740,7 @@ def _jitted_reduce(
             sorted_within=sorted_within,
             f_tile=f_tile,
             in_bounds=in_bounds,
+            vmem_budget=vmem_budget,
         )
 
     return jax.jit(f)
@@ -711,6 +753,9 @@ class PBExecutor:
     default from ``get_default_executor()``. ``autotune=True`` makes
     ``decide`` measure every candidate method on a synthetic stream of
     the requested shape (once per key; results cached and persisted).
+    ``hw=None`` takes the model of the attached TPU by its
+    ``device_kind`` (an unknown kind is an error); other backends model
+    the v5e the repo targets.
     """
 
     def __init__(
@@ -723,13 +768,11 @@ class PBExecutor:
         block: int = 2048,
         interpret: Optional[bool] = None,
     ):
-        self.hw = hw or HardwareModel.tpu_v5e()
+        self.hw = hw or HardwareModel.attached()
         self.autotune = autotune
         self.use_pallas = use_pallas
         self.block = block
-        self.interpret = (
-            interpret if interpret is not None else jax.default_backend() != "tpu"
-        )
+        self.interpret = resolve_interpret(interpret)
         self.cache = _AutotuneCache(cache_dir)
         # every decide() appends here — benchmarks/run.py serializes it
         # into BENCH_smoke.json so PRs have a method-decision trajectory
@@ -740,6 +783,9 @@ class PBExecutor:
         # sees decisions after the shared log saturates
         self._decision_sinks: list = []
         self._last_entry: Optional[dict] = None
+        # host seconds and upload bytes of traversal level streams
+        # (core/traversal.py), read per phase by chip_smoke.py
+        self.host_streams = HostStreamStats()
 
     # -- decision ----------------------------------------------------------
 
@@ -817,7 +863,12 @@ class PBExecutor:
             return BinningDecision(
                 method, plan.final_bin_range, plan.num_bins, plan, source
             )
-        r = bin_range or max(1, min(compromise_bin_range(num_indices, self.hw), num_indices))
+        if method == "fused":
+            # the fused method's range is its C-Buffer geometry
+            default = FUSED_BIN_RANGE
+        else:
+            default = compromise_bin_range(num_indices, self.hw)
+        r = bin_range or max(1, min(default, num_indices))
         return BinningDecision(method, r, num_bins_for_range(num_indices, r), None, source)
 
     def analytic_method(
@@ -835,29 +886,38 @@ class PBExecutor:
             return "pallas" if self.use_pallas else "counting"
         return "hierarchical"
 
-    def fused_fits(self, num_indices: int, value_bytes: int = 4) -> bool:
-        """Fusion legality, capacity half (DESIGN.md §8.1): the dense
-        accumulator (one output per index) must be resident in the fast
-        hierarchy alongside the C-Buffers — budget half of the largest
-        fast level (on TPU the only level: VMEM; on the modeled Xeon the
-        LLC, where the paper parks Bin-Read working sets)."""
-        return num_indices * value_bytes <= self.hw.fast_levels[-1] // 2
+    def fused_fits(
+        self,
+        num_indices: int,
+        itemsize: int = 4,
+        f_tile: int = 0,
+        bin_range: Optional[int] = None,
+    ) -> bool:
+        """Fusion legality, capacity half (DESIGN.md §8.1): the fused
+        kernel's footprint (``plan.fused_vmem_bytes``: accumulator plus
+        C-Buffers, at ``f_tile`` columns for a row block) fits the largest
+        fast level — on a TPU the only level, VMEM, and the limit the
+        kernel is compiled with."""
+        return fused_fits(
+            self.hw, num_indices, bin_range=bin_range or FUSED_BIN_RANGE,
+            itemsize=itemsize, f_tile=f_tile,
+        )
 
     def analytic_reduce_method(
         self,
         num_indices: int,
         stream_len: int,
         bin_range: Optional[int] = None,
-        value_bytes: int = 4,
+        itemsize: int = 4,
+        f_tile: int = 0,
     ) -> str:
         """DESIGN.md §8: the fused single sweep strictly halves stream
         bytes whenever its accumulator fits the fast level, so it wins
         every bandwidth-bound case; oversized domains fall back to the
-        two-phase tree at §3.1. ``value_bytes`` is the per-INDEX
-        accumulator cost — a row-block stream passes ``F * itemsize``,
-        but feature tiling (§14) caps what must actually be resident, so
-        legality is checked at the chosen F-tile, never at full F."""
-        if self.fused_fits(num_indices, value_bytes):
+        two-phase tree at §3.1. A row-block stream is checked at the
+        F-tile the policy picks (``choose_f_tile``), never at full F:
+        feature tiling (§14) caps what must be resident."""
+        if self.fused_fits(num_indices, itemsize, f_tile, bin_range):
             return "fused"
         return self.analytic_method(num_indices, stream_len, bin_range)
 
@@ -866,26 +926,25 @@ class PBExecutor:
         feature_dim: int,
         num_indices: int,
         itemsize: int = 4,
-        cap: int = 512,
     ) -> int:
-        """F-tiling policy (DESIGN.md §14): the widest power-of-two slab
-        of feature columns whose VMEM-resident footprint — the
-        ``num_indices``-wide accumulator tile plus the per-bin C-Buffer
-        row scratch — fits half the fast level, clamped to the 128-lane
-        register width. The F-tile loop is OUTERMOST in the kernel, so
-        the binned index stream is re-streamed ``ceil(F / f_tile)``
-        times; wider tiles amortize those re-reads, which is why the
-        policy maximizes rather than minimizes. Returns 0 for scalar
-        (``feature_dim == 0``) streams."""
+        """F-tiling policy (DESIGN.md §14): the widest slab of feature
+        columns the compiled kernel can block — all of F, a multiple of
+        the 128-lane register width, or one column (the scalar kernel per
+        column) — whose footprint (``plan.fused_vmem_bytes``) fits the
+        fast level. The F-tile loop is OUTERMOST, so the binned index
+        stream is re-streamed ``ceil(F / f_tile)`` times; wider tiles
+        amortize those re-reads, which is why the policy maximizes rather
+        than minimizes. Where nothing fits, the single column
+        (``fused_fits`` then refuses the fused method). Returns 0 for
+        scalar (``feature_dim == 0``) streams."""
         if feature_dim <= 0:
             return 0
-        budget = self.hw.fast_levels[-1] // 2
-        # per feature column: one accumulator slot per owned index plus
-        # one C-Buffer slot per (bin, lane) — bins ~ num_indices / range
-        per_col = max(1, num_indices + cap * max(1, num_indices // 512)) * itemsize
-        max_ft = max(1, budget // per_col)
-        ft = min(feature_dim, max_ft, 128)
-        return 1 << (int(ft).bit_length() - 1)  # power-of-two slab
+        lanes = 128
+        wide = range((feature_dim - 1) // lanes * lanes, 0, -lanes)
+        for ft in (feature_dim, *wide):
+            if self.fused_fits(num_indices, itemsize, ft):
+                return ft
+        return 1
 
     def decide(
         self,
@@ -956,8 +1015,34 @@ class PBExecutor:
             entry["mesh"] = {a: s for a, s in mesh_shape}
             if kind == "reduce":
                 entry["pipeline_chunks"] = d.pipeline_chunks
+        vshape = (feature_dim,) if feature_dim else ()
+        entry.update(
+            self._realization(
+                num_indices, (stream_len,) + vshape, dtype, d, sharded=bool(mesh_shape)
+            )
+        )
         self._log_decision(entry)
         return d
+
+    def _realization(
+        self, num_indices: int, values_shape, dtype, d: BinningDecision,
+        sharded: bool = False,
+    ) -> dict:
+        """``{"realization": ...}`` for a fused decision (empty for any
+        other method): what one ``execute_reduce`` of this stream shape
+        runs under this executor. A sharded decision's device-local
+        reduce runs as ``distributed_pb`` calls it (default budget, no
+        ``use_pallas``); ``reduce_streams`` names its own per-lane one."""
+        if d.method != "fused":
+            return {}
+        return {
+            "realization": fused_realization(
+                num_indices, tuple(values_shape), dtype, interpret=self.interpret,
+                use_pallas=self.use_pallas and not sharded, bin_range=d.bin_range,
+                f_tile=d.f_tile or None,
+                vmem_budget=None if sharded else self.hw.fast_levels[-1],
+            )
+        }
 
     def _log_decision(self, entry: dict) -> None:
         """Append one decision record to the bounded shared log and every
@@ -1054,7 +1139,7 @@ class PBExecutor:
             isz = jnp.dtype(dtype).itemsize
             ft = self.choose_f_tile(feature_dim, num_indices, isz)
             analytic = self.analytic_reduce_method(
-                num_indices, stream_len, bin_range, value_bytes=max(1, ft) * isz
+                num_indices, stream_len, bin_range, itemsize=isz, f_tile=ft
             )
         else:
             analytic = self.analytic_method(num_indices, stream_len, bin_range)
@@ -1185,6 +1270,7 @@ class PBExecutor:
                 fn = _jitted_reduce(
                     num_indices, d.bin_range, d.num_bins, method, op, self.block,
                     self.interpret, d.plan, self.use_pallas, None, ftile, False,
+                    self.hw.fast_levels[-1],
                 )
             else:
                 fn = _jitted_binning(
@@ -1425,6 +1511,7 @@ class PBExecutor:
                         "bin_range": d.bin_range,
                         "source": d.source,
                         "op": op,
+                        **self._realization(out_size, values.shape, vdtype, d),
                     }
                 )
         if not flat and d.method != "fused":
@@ -1439,7 +1526,7 @@ class PBExecutor:
         fn = _jitted_reduce(
             out_size, d.bin_range, d.num_bins, d.method, op, self.block,
             self.interpret, d.plan, self.use_pallas, sorted_within,
-            d.f_tile or None, in_bounds,
+            d.f_tile or None, in_bounds, self.hw.fast_levels[-1],
         )
         return fn(indices, values)
 
@@ -1523,9 +1610,18 @@ class PBExecutor:
                     f"got {method!r}"
                 )
             d = self._finalize(method, out_size, bin_range, "caller")
+        if d.method == "fused" and method in (None, "auto"):
+            # per lane, as ``_jitted_reduce_batched`` realizes it: the
+            # compiled kernel where it is compiled (a TPU) and fits at
+            # full F; ``use_pallas`` never interprets it per lane
+            self._last_entry["realization"] = fused_realization(
+                out_size, tuple(values.shape[1:]), values.dtype,
+                interpret=self.interpret, bin_range=d.bin_range,
+                vmem_budget=self.hw.fast_levels[-1],
+            )
         fn = _jitted_reduce_batched(
             out_size, d.bin_range, d.num_bins, d.method, op, self.block,
-            self.interpret, sorted_within,
+            self.interpret, sorted_within, self.hw.fast_levels[-1],
         )
         return fn(indices, values)
 

@@ -218,19 +218,21 @@ def gen_kron(scale: int, avg_degree: int, seed: int = 0) -> COO:
     n = 1 << scale
     m = n * avg_degree
     a, b, c = 0.57, 0.19, 0.19
-    src = np.zeros(m, dtype=np.int64)
-    dst = np.zeros(m, dtype=np.int64)
+    # P(dst bit set | src bit clear), P(dst bit set | src bit set)
+    p_right_dst = (a / (a + b), c / (c + (1 - a - b - c)))
+    src = np.zeros(m, dtype=np.int32)
+    dst = np.zeros(m, dtype=np.int32)
+    r = np.empty(m)  # one draw buffer, refilled in place (same stream)
     for bit in range(scale):
-        r = rng.random(m)
+        rng.random(out=r)
         # quadrant choice per RMAT
         go_right_src = r >= a + b  # bottom half -> src bit set
-        r2 = rng.random(m)
-        p_right_dst = np.where(go_right_src, c / (c + (1 - a - b - c)), a / (a + b))
-        go_right_dst = r2 >= p_right_dst
-        src |= go_right_src.astype(np.int64) << bit
-        dst |= go_right_dst.astype(np.int64) << bit
-    perm = rng.permutation(n)  # avoid locality from bit construction
-    return _to_coo(perm[src].astype(np.int32), perm[dst].astype(np.int32), n)
+        rng.random(out=r)
+        go_right_dst = np.where(go_right_src, r >= p_right_dst[1], r >= p_right_dst[0])
+        src |= np.left_shift(go_right_src, bit, dtype=np.int32)
+        dst |= np.left_shift(go_right_dst, bit, dtype=np.int32)
+    perm = rng.permutation(n).astype(np.int32)  # avoid locality from bit construction
+    return _to_coo(perm[src], perm[dst], n)
 
 
 def gen_powerlaw(num_nodes: int, avg_degree: int, seed: int = 0, alpha: float = 1.8) -> COO:

@@ -367,15 +367,21 @@ def pagerank_sharded(
         d.bin_range, d.plan,
     )
     ranks, overflow = fn(src_p, dst_p, outdeg, ranks0)
-    if cap < chunk_len and bool(overflow):
+    overflow = cap < chunk_len and bool(overflow)
+    if overflow:
         # estimated capacity lost tuples: rerun at the always-safe
         # per-chunk capacity (surfaced on the decision entry)
+        cap = chunk_len
         fn = _pr_sharded_fn(
-            mesh, axis, n, n_dev, r, iters, d.method, ex.block, chunk_len, k,
+            mesh, axis, n, n_dev, r, iters, d.method, ex.block, cap, k,
             d.bin_range, d.plan,
         )
         ranks, _ = fn(src_p, dst_p, outdeg, ranks0)
-        if entry is not None:
-            entry.update(overflow=True, capacity=chunk_len,
-                         capacity_source="overflow-fallback")
+    if entry is not None:
+        entry.update(
+            overflow=overflow, capacity=cap, pipeline_chunks=k,
+            capacity_source="overflow-fallback" if overflow else (
+                "caller" if capacity is not None else "estimated"
+            ),
+        )
     return PRResult(ranks, iters)

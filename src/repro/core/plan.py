@@ -47,8 +47,10 @@ class HardwareModel:
 
     @staticmethod
     def tpu_v5e() -> "HardwareModel":
-        # One fast level (VMEM ~128MiB shared by scratch; budget half for
-        # C-Buffers) but multiple *passes* give the hierarchy.
+        # One fast level (VMEM, 128 MiB per core) but multiple *passes*
+        # give the hierarchy. The level is budgeted at half of VMEM, and
+        # that one number is also the ``vmem_limit_bytes`` the compiled
+        # fused kernel is built with (core/executor.py, kernels/fused.py).
         return HardwareModel(
             name="tpu-v5e",
             fast_levels=(64 * 1024 * 1024,),
@@ -57,8 +59,95 @@ class HardwareModel:
             fast_bandwidth=20e12,  # VMEM
         )
 
+    @staticmethod
+    def for_device_kind(kind: str) -> "HardwareModel":
+        """The model of a TPU by its ``device_kind``. A kind that is not
+        in the table is an error, never an assumed v5e."""
+        models = {"TPU v5 lite": HardwareModel.tpu_v5e}
+        if kind not in models:
+            raise ValueError(
+                f"no HardwareModel for TPU device_kind {kind!r} "
+                f"(known: {sorted(models)})"
+            )
+        return models[kind]()
+
+    @staticmethod
+    def attached() -> "HardwareModel":
+        """The model of the attached device: a TPU by its ``device_kind``
+        (``for_device_kind``), any other backend models the v5e the
+        repo targets."""
+        import jax
+
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            return HardwareModel.for_device_kind(dev.device_kind)
+        return HardwareModel.tpu_v5e()
+
 
 TUPLE_BYTES = 8  # (index, value) int32 pairs, as in the paper
+
+# Geometry of the compiled fused C-Buffer kernels (kernels/fused.py):
+# 2048-index bins (16 accumulator lane rows each) and 1024-tuple
+# C-Buffers (8 lane rows, one (8, 128) tile to transpose per flush).
+# ``fused_vmem_bytes`` is the kernel's footprint at this geometry, and
+# the one VMEM budget is the fast level of the ``HardwareModel``: fused
+# legality (``fused_fits``), the F-tile policy, the realization and the
+# compiled ``vmem_limit_bytes`` all compare against it.
+FUSED_BIN_RANGE = 2048
+FUSED_CAP = 1024
+# Indices per SMEM block (the TPU lays 1-D int32 arrays out in
+# 1024-word tiles, so blocks are multiples of 1024).
+FUSED_BLOCK = 2048
+# Mosaic's own scratch beside the kernel's buffers (vreg spills, the
+# flush's transposed tiles).
+_FUSED_SCRATCH_BYTES = 1 << 20
+
+
+def fused_vmem_bytes(
+    num_indices: int,
+    *,
+    bin_range: int = FUSED_BIN_RANGE,
+    cap: int = FUSED_CAP,
+    itemsize: int = 4,
+    f_tile: int = 0,
+) -> int:
+    """VMEM the fused kernel keeps resident: accumulator, C-Buffer
+    offsets and values, with the TPU's (8, 128) tile padding, plus the
+    flush's working tiles. ``f_tile <= 1`` is the scalar kernel (a width-1
+    tile of a row block runs it once per column); ``f_tile > 1`` the
+    row-block kernel at that F-tile, whose ``add`` flush also holds a
+    one-hot tile and a partial sum of the bin's accumulator."""
+    lanes = 128
+    nb = math.ceil(max(1, num_indices) / bin_range)
+    rows_per_buffer = math.ceil(cap // lanes / 8) * 8
+    offsets = nb * rows_per_buffer * lanes * 4
+    if f_tile > 1:
+        cols = math.ceil(f_tile / lanes) * lanes
+        rows = math.ceil(bin_range / 8) * 8
+        resident = nb * (rows + cap) * cols * itemsize
+        flush = rows * (lanes + 2 * cols) * 4
+        return offsets + resident + flush + _FUSED_SCRATCH_BYTES
+    acc = nb * math.ceil(math.ceil(bin_range / lanes) / 8) * 8 * lanes
+    values = nb * rows_per_buffer * lanes
+    return offsets + (acc + values) * itemsize + _FUSED_SCRATCH_BYTES
+
+
+def fused_fits(
+    hw: HardwareModel,
+    num_indices: int,
+    *,
+    bin_range: int = FUSED_BIN_RANGE,
+    itemsize: int = 4,
+    f_tile: int = 0,
+) -> bool:
+    """Fusion legality, capacity half (DESIGN.md §8.1): the fused
+    kernel's resident footprint fits the largest fast level (on a TPU
+    the only one, VMEM, which is also the limit the kernel compiles
+    with)."""
+    need = fused_vmem_bytes(
+        num_indices, bin_range=bin_range, itemsize=itemsize, f_tile=f_tile
+    )
+    return need <= hw.fast_levels[-1]
 
 
 def num_bins_for_range(num_indices: int, bin_range: int) -> int:
@@ -139,7 +228,7 @@ class CobraPlan:
         """Derive the knob-free plan (paper §4.2). ``final_bin_range``
         overrides the Bin-Read-optimal range when a consumer needs bins at
         a specific granularity (e.g. a pre-binned PageRank loop)."""
-        hw = hw or HardwareModel.tpu_v5e()
+        hw = hw or HardwareModel.attached()
         final_range = final_bin_range or min(
             binread_optimal_range(hw, value_bytes_per_index), num_indices
         )

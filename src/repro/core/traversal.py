@@ -10,11 +10,12 @@ where cache-aware blocking is hardest ("Making Caches Work for Graph
 Analytics"; GraphCage's bin-aware frontier scheduling), and it is served
 here with three ingredients:
 
-  expansion — ``_expand_frontier`` gathers the CSR out-edges of the
-      current frontier into a **fixed-size** stream: the frontier and
-      the edge stream are padded to power-of-two buckets
-      (``bucket_len``), so jit caches are keyed on O(log m) shapes
-      instead of retracing per frontier size. Padding slots carry an
+  expansion — ``_expand_frontiers`` gathers the CSR out-edges of the
+      current frontier (on the host, where the frontier is compacted)
+      into a **fixed-size** stream: the edge stream is padded to a
+      power-of-two bucket (``bucket_len``), so the device's reduce
+      programs are keyed on O(log m) shapes instead of one per frontier
+      size. Padding slots carry an
       IN-RANGE index and the reduce op's identity value, which makes
       them a no-op for every executor method (the clamp trick
       ``distributed_pb.clamp_for_local_reduce`` established — an
@@ -41,17 +42,17 @@ workload. Traffic/roofline counterparts: ``traffic.traversal_bytes``,
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.executor import PBExecutor, get_default_executor
+from repro.core.executor import HostStreamStats, PBExecutor, get_default_executor
 from repro.core.graph import CSR
 
 _INT_MAX = np.iinfo(np.int32).max
+_INT_MIN = np.iinfo(np.int32).min
 _F32_MAX = float(np.finfo(np.float32).max)
 
 # Methods the per-level reduction accepts: the executor's reduce set
@@ -77,30 +78,68 @@ def bucket_len(n: int, minimum: int = 256) -> int:
     return b
 
 
-@functools.partial(jax.jit, static_argnames=("bucket_edges",))
-def _expand_frontier(offsets, neighs, ids, count, bucket_edges):
-    """Gather the out-edges of ``ids[:count]`` into fixed-size arrays.
+class _Edges(NamedTuple):
+    """One level's frontier out-edges, (B, bucket) host arrays (one lane
+    per frontier): destination vertex, the edge's CSR slot (for weight
+    gathers), the owning frontier vertex, and the validity mask. Padding
+    slots point at CSR slot 0 — an in-range index — and callers give
+    them the reduce op's identity value via ``ok``."""
 
-    Returns ``(nbr, src, pos, ok)``, each of length ``bucket_edges``:
-    destination vertex, owning frontier vertex, the edge's slot in the
-    CSR neighbor array (for weight gathers), and the validity mask.
-    Invalid slots hold clamped in-range values — callers mask them with
-    ``ok`` (values to the op identity), never by index.
-    """
-    nf = ids.shape[0]
-    valid = jnp.arange(nf, dtype=jnp.int32) < count
-    ids_c = jnp.where(valid, ids, 0)
-    deg = jnp.where(valid, offsets[ids_c + 1] - offsets[ids_c], 0)
-    cum = jnp.cumsum(deg, dtype=jnp.int32)  # inclusive prefix
-    total = cum[-1]
-    j = jnp.arange(bucket_edges, dtype=jnp.int32)
-    seg = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-    seg = jnp.minimum(seg, nf - 1)
-    start = cum[seg] - deg[seg]  # exclusive prefix of the owning vertex
-    v = ids_c[seg]
-    pos = jnp.clip(offsets[v] + (j - start), 0, neighs.shape[0] - 1)
-    ok = j < total
-    return neighs[pos], v, pos, ok
+    nbr: np.ndarray
+    pos: np.ndarray
+    src: np.ndarray
+    ok: np.ndarray
+
+
+def _expand_frontiers(
+    stats: HostStreamStats, offs_host, neighs_host, fronts, bucket_edges: int
+) -> _Edges:
+    """Gather the CSR out-edges of each host frontier into one row of
+    fixed-size ``bucket_edges`` arrays.
+
+    The expansion is host numpy: the frontiers are compacted on the
+    host anyway, a frontier's edge slots are runs of consecutive CSR
+    positions (``np.repeat`` of the run starts), and on a TPU the device
+    rendering of the same expansion (a searchsorted over the frontier's
+    degree prefix plus gathers) compiled a new program per bucket shape
+    in tens of seconds (DESIGN.md §11.1). Only the reduce runs on the
+    device, on the streams built here."""
+    with stats.timed():
+        B = len(fronts)
+        pos = np.zeros((B, bucket_edges), np.int32)
+        src = np.zeros((B, bucket_edges), np.int32)
+        ok = np.zeros((B, bucket_edges), bool)
+        for q, f in enumerate(fronts):
+            starts = offs_host[f]
+            deg = offs_host[f + 1] - starts
+            total = int(deg.sum())
+            # slot j of frontier vertex k sits at starts[k] + (j - excl[k])
+            pos[q, :total] = np.arange(total, dtype=np.int32) + np.repeat(
+                starts - (np.cumsum(deg) - deg), deg
+            )
+            src[q, :total] = np.repeat(f, deg)
+            ok[q, :total] = True
+        stats.levels += 1
+        return _Edges(neighs_host[pos], pos, src, ok)
+
+
+def _seeded(stats: HostStreamStats, srcs, n: int, hot, cold, dtype) -> jnp.ndarray:
+    """(B, n) device array: ``hot`` at ``(q, srcs[q])`` (``hot`` a scalar
+    or one value per lane), ``cold`` elsewhere. Built on the host: a
+    device scatter would compile one program per (B, n)."""
+    with stats.timed():
+        srcs = np.atleast_1d(np.asarray(srcs))
+        a = np.full((srcs.size, n), cold, dtype)
+        a[np.arange(srcs.size), srcs] = hot
+    return stats.upload(a)
+
+
+@jax.jit
+def _improve(cand, dist):
+    """``(cand < dist, min(cand, dist))``: the relaxation's new frontier
+    mask and distances, one program per (B, n)."""
+    better = cand < dist
+    return better, jnp.where(better, cand, dist)
 
 
 class TraversalResult(NamedTuple):
@@ -208,11 +247,26 @@ def _resolve_batched(method: str):
         )
 
 
-def _pad_frontier(frontier: np.ndarray) -> Tuple[jnp.ndarray, int]:
-    bf = bucket_len(frontier.size)
-    ids = np.zeros(bf, np.int32)
-    ids[: frontier.size] = frontier
-    return jnp.asarray(ids), frontier.size
+def _masked(
+    stats: HostStreamStats, ok: np.ndarray, real: np.ndarray, identity, dtype
+) -> jnp.ndarray:
+    """A level's reduce values on the device: ``real`` at valid slots,
+    the op's ``identity`` at padding."""
+    with stats.timed():
+        a = np.where(ok, real, identity).astype(dtype)
+    return stats.upload(a)
+
+
+def _relax_values(
+    stats: HostStreamStats, e: _Edges, dist_host: np.ndarray, w_host: np.ndarray
+) -> jnp.ndarray:
+    """SSSP reduce values ``dist[u] + w(u, v)`` (float32, as the device
+    adds them) for (B, n) host distances; ``float32 max`` at padding."""
+    with stats.timed():
+        val = np.full(e.ok.shape, _F32_MAX, np.float32)
+        d = np.take_along_axis(dist_host, e.src, axis=1)
+        val[e.ok] = d[e.ok] + w_host[e.pos[e.ok]]
+    return stats.upload(val)
 
 
 def bfs(
@@ -237,19 +291,16 @@ def bfs(
     """
     _resolve(method)
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     if not 0 <= source < n:
         raise ValueError(f"source {source} outside [0, {n})")
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, mesh, axis_name)
 
-    dist = jnp.full((n,), _INT_MAX, jnp.int32).at[source].set(0)
-    parent = (
-        jnp.full((n,), -1, jnp.int32).at[source].set(source)
-        if with_parents
-        else None
-    )
+    dist = _seeded(hs, source, n, 0, _INT_MAX, np.int32)[0]
+    parent = _seeded(hs, source, n, source, -1, np.int32)[0] if with_parents else None
     frontier = np.asarray([source], np.int32)
     sizes = [1]
     edges = []
@@ -267,16 +318,13 @@ def bfs(
             frontier = np.zeros(0, np.int32)
             sizes.append(0)
             break
-        ids, count = _pad_frontier(frontier)
-        be = bucket_len(total)
-        nbr, srcv, _, ok = _expand_frontier(
-            csr.offsets, csr.neighs, ids, count, be
-        )
-        val = jnp.where(ok, jnp.int32(level + 1), jnp.int32(_INT_MAX))
+        e = _expand_frontiers(hs, offs_host, neighs_host, [frontier], bucket_len(total))
+        nbr = hs.upload(e.nbr[0])
+        val = _masked(hs, e.ok[0], level + 1, _INT_MAX, np.int32)
         cand = red(nbr, val, out_size=n, op="min")
         newly = cand < dist
         if with_parents:
-            pval = jnp.where(ok, srcv, jnp.int32(np.iinfo(np.int32).min))
+            pval = _masked(hs, e.ok[0], e.src[0], _INT_MIN, np.int32)
             pmax = red(nbr, pval, out_size=n, op="max")
             parent = jnp.where(newly, pmax, parent)
         dist = jnp.where(newly, cand, dist)
@@ -318,6 +366,7 @@ def sssp(
     """
     _resolve(method)
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     if not 0 <= source < n:
         raise ValueError(f"source {source} outside [0, {n})")
@@ -326,12 +375,12 @@ def sssp(
             f"weights must align with csr.neighs: {weights.shape[0]} != "
             f"{csr.num_edges}"
         )
-    w = weights.astype(jnp.float32)
+    w_host = np.asarray(weights, np.float32)
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, mesh, axis_name)
 
-    dist = jnp.full((n,), _F32_MAX, jnp.float32).at[source].set(0.0)
+    dist = _seeded(hs, source, n, 0.0, _F32_MAX, np.float32)[0]
     frontier = np.asarray([source], np.int32)
     sizes = [1]
     edges = []
@@ -345,13 +394,9 @@ def sssp(
             frontier = np.zeros(0, np.int32)
             sizes.append(0)
             break
-        ids, count = _pad_frontier(frontier)
-        be = bucket_len(total)
-        nbr, srcv, pos, ok = _expand_frontier(
-            csr.offsets, csr.neighs, ids, count, be
-        )
-        val = jnp.where(ok, dist[srcv] + w[pos], jnp.float32(_F32_MAX))
-        cand = red(nbr, val, out_size=n, op="min")
+        e = _expand_frontiers(hs, offs_host, neighs_host, [frontier], bucket_len(total))
+        val = _relax_values(hs, e, np.asarray(dist)[None], w_host)[0]
+        cand = red(hs.upload(e.nbr[0]), val, out_size=n, op="min")
         improved = cand < dist
         dist = jnp.where(improved, cand, dist)
         frontier = np.flatnonzero(np.asarray(improved)).astype(np.int32)
@@ -392,38 +437,31 @@ def k_core(
     if k < 0:
         raise ValueError(f"k must be >= 0, got {k}")
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, mesh, axis_name)
 
     deg = (csr.offsets[1:] - csr.offsets[:-1]).astype(jnp.int32)
-    alive = jnp.ones((n,), jnp.bool_)
+    alive = np.ones(n, bool)  # host-side: the frontier is compacted there
     frontier = np.flatnonzero(np.asarray(deg) < k).astype(np.int32)
     removed = [int(frontier.size)] if frontier.size else []
     rounds = 0
     while frontier.size and rounds < max_iters:
         red.set_level(rounds)
-        alive = alive.at[jnp.asarray(frontier)].set(False)
+        alive[frontier] = False
         total = int((offs_host[frontier + 1] - offs_host[frontier]).sum())
         if total:
-            ids, count = _pad_frontier(frontier)
-            be = bucket_len(total)
-            nbr, _, _, ok = _expand_frontier(
-                csr.offsets, csr.neighs, ids, count, be
-            )
-            dec = red(
-                nbr, jnp.where(ok, 1, 0).astype(jnp.int32), out_size=n, op="add"
-            )
-            deg = deg - dec
-        frontier = np.flatnonzero(
-            np.asarray(alive) & (np.asarray(deg) < k)
-        ).astype(np.int32)
+            e = _expand_frontiers(hs, offs_host, neighs_host, [frontier], bucket_len(total))
+            ones = _masked(hs, e.ok[0], 1, 0, np.int32)
+            deg = deg - red(hs.upload(e.nbr[0]), ones, out_size=n, op="add")
+        frontier = np.flatnonzero(alive & (np.asarray(deg) < k)).astype(np.int32)
         if frontier.size:
             removed.append(int(frontier.size))
         rounds += 1
     return KCoreResult(
-        in_core=alive,
+        in_core=jnp.asarray(alive),
         rounds=rounds,
         converged=frontier.size == 0,
         removed_per_round=tuple(removed),
@@ -459,6 +497,7 @@ def bfs_incremental(
     """
     _resolve(method)
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     if not 0 <= source < n:
         raise ValueError(f"source {source} outside [0, {n})")
@@ -471,7 +510,7 @@ def bfs_incremental(
             "full",
         )
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, None, None)
 
     dist = jnp.asarray(dist_prev, jnp.int32)
@@ -491,17 +530,14 @@ def bfs_incremental(
             frontier = np.zeros(0, np.int32)
             sizes.append(0)
             break
-        ids, count = _pad_frontier(frontier)
-        be = bucket_len(total)
-        nbr, srcv, _, ok = _expand_frontier(
-            csr.offsets, csr.neighs, ids, count, be
-        )
+        e = _expand_frontiers(hs, offs_host, neighs_host, [frontier], bucket_len(total))
         # frontier vertices sit at heterogeneous levels after a batch,
         # so relax dist[u] + 1 (unit-weight sssp) rather than level + 1
-        val = jnp.where(ok, dist[srcv] + 1, jnp.int32(_INT_MAX))
-        cand = red(nbr, val, out_size=n, op="min")
+        val = _masked(hs, e.ok[0], dist_host[e.src[0]] + 1, _INT_MAX, np.int32)
+        cand = red(hs.upload(e.nbr[0]), val, out_size=n, op="min")
         improved = cand < dist
         dist = jnp.where(improved, cand, dist)
+        dist_host = np.asarray(dist)
         frontier = np.flatnonzero(np.asarray(improved)).astype(np.int32)
         sizes.append(int(frontier.size))
         rounds += 1
@@ -522,19 +558,6 @@ def bfs_incremental(
 # ---------------------------------------------------------------------------
 # Micro-batched traversal: many source-vertex queries per reduce call.
 # ---------------------------------------------------------------------------
-
-
-def _pad_frontiers(fronts) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Pad a batch of host frontiers to one common power-of-two bucket:
-    (B, bf) ids + (B,) counts. One bucket for the whole batch keeps the
-    vmapped expansion at a single static shape per level."""
-    bf = bucket_len(max(f.size for f in fronts))
-    ids = np.zeros((len(fronts), bf), np.int32)
-    counts = np.zeros((len(fronts),), np.int32)
-    for q, f in enumerate(fronts):
-        ids[q, : f.size] = f
-        counts[q] = f.size
-    return jnp.asarray(ids), jnp.asarray(counts)
 
 
 def bfs_batched(
@@ -561,6 +584,7 @@ def bfs_batched(
     """
     _resolve_batched(method)
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     srcs = np.atleast_1d(np.asarray(sources, np.int32))
     if srcs.size == 0:
@@ -569,17 +593,11 @@ def bfs_batched(
         raise ValueError(f"sources outside [0, {n}): {srcs}")
     B = srcs.size
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, None, None)
 
-    dist = jnp.full((B, n), _INT_MAX, jnp.int32)
-    dist = dist.at[jnp.arange(B), jnp.asarray(srcs)].set(0)
-    parent = None
-    if with_parents:
-        parent = jnp.full((B, n), -1, jnp.int32)
-        parent = parent.at[jnp.arange(B), jnp.asarray(srcs)].set(
-            jnp.asarray(srcs)
-        )
+    dist = _seeded(hs, srcs, n, 0, _INT_MAX, np.int32)
+    parent = _seeded(hs, srcs, n, srcs, -1, np.int32) if with_parents else None
     fronts = [np.asarray([s], np.int32) for s in srcs]
     sizes = [B]
     edges = []
@@ -597,19 +615,15 @@ def bfs_batched(
             fronts = [np.zeros(0, np.int32) for _ in fronts]
             sizes.append(0)
             break
-        ids, counts = _pad_frontiers(fronts)
-        be = bucket_len(max(per_q))
-        nbr, srcv, _, ok = jax.vmap(
-            lambda i, c: _expand_frontier(csr.offsets, csr.neighs, i, c, be)
-        )(ids, counts)
-        val = jnp.where(ok, jnp.int32(level + 1), jnp.int32(_INT_MAX))
+        e = _expand_frontiers(hs, offs_host, neighs_host, fronts, bucket_len(max(per_q)))
+        nbr = hs.upload(e.nbr)
+        val = _masked(hs, e.ok, level + 1, _INT_MAX, np.int32)
         cand = red.batched(nbr, val, out_size=n, op="min")
-        newly = cand < dist
+        newly, dist = _improve(cand, dist)
         if with_parents:
-            pval = jnp.where(ok, srcv, jnp.int32(np.iinfo(np.int32).min))
+            pval = _masked(hs, e.ok, e.src, _INT_MIN, np.int32)
             pmax = red.batched(nbr, pval, out_size=n, op="max")
             parent = jnp.where(newly, pmax, parent)
-        dist = jnp.where(newly, cand, dist)
         newly_np = np.asarray(newly)
         fronts = [np.flatnonzero(newly_np[q]).astype(np.int32) for q in range(B)]
         sizes.append(int(sum(f.size for f in fronts)))
@@ -640,6 +654,7 @@ def sssp_batched(
     weights, sources[q])`` under the same reduce method."""
     _resolve_batched(method)
     ex = executor or get_default_executor()
+    hs = ex.host_streams
     n = csr.num_nodes
     if weights.shape[0] != csr.num_edges:
         raise ValueError(
@@ -652,13 +667,12 @@ def sssp_batched(
     if not ((srcs >= 0) & (srcs < n)).all():
         raise ValueError(f"sources outside [0, {n}): {srcs}")
     B = srcs.size
-    w = weights.astype(jnp.float32)
+    w_host = np.asarray(weights, np.float32)
     max_iters = n if max_iters is None else max_iters
-    offs_host = np.asarray(csr.offsets)
+    offs_host, neighs_host = np.asarray(csr.offsets), np.asarray(csr.neighs)
     red = _LevelReducer(ex, method, None, None)
 
-    dist = jnp.full((B, n), _F32_MAX, jnp.float32)
-    dist = dist.at[jnp.arange(B), jnp.asarray(srcs)].set(0.0)
+    dist = _seeded(hs, srcs, n, 0.0, _F32_MAX, np.float32)
     fronts = [np.asarray([s], np.int32) for s in srcs]
     sizes = [B]
     edges = []
@@ -676,16 +690,10 @@ def sssp_batched(
             fronts = [np.zeros(0, np.int32) for _ in fronts]
             sizes.append(0)
             break
-        ids, counts = _pad_frontiers(fronts)
-        be = bucket_len(max(per_q))
-        nbr, srcv, pos, ok = jax.vmap(
-            lambda i, c: _expand_frontier(csr.offsets, csr.neighs, i, c, be)
-        )(ids, counts)
-        relax = jnp.take_along_axis(dist, srcv, axis=1) + w[pos]
-        val = jnp.where(ok, relax, jnp.float32(_F32_MAX))
-        cand = red.batched(nbr, val, out_size=n, op="min")
-        improved = cand < dist
-        dist = jnp.where(improved, cand, dist)
+        e = _expand_frontiers(hs, offs_host, neighs_host, fronts, bucket_len(max(per_q)))
+        val = _relax_values(hs, e, np.asarray(dist), w_host)
+        cand = red.batched(hs.upload(e.nbr), val, out_size=n, op="min")
+        improved, dist = _improve(cand, dist)
         improved_np = np.asarray(improved)
         fronts = [
             np.flatnonzero(improved_np[q]).astype(np.int32) for q in range(B)
@@ -706,6 +714,17 @@ def sssp_batched(
 # ---------------------------------------------------------------------------
 # Personalized PageRank: restart mass as an op=add reduce stream.
 # ---------------------------------------------------------------------------
+
+
+@jax.jit
+def _ppr_contrib(ranks, outdeg, src):
+    """Each edge's (m, B) contribution ``ranks[u] / outdeg[u]``."""
+    return jnp.take(ranks / outdeg[:, None], src, axis=0)
+
+
+@jax.jit
+def _ppr_restart(restart, incoming, damp):
+    return (1.0 - damp) * restart + damp * incoming
 
 
 class PPRResult(NamedTuple):
@@ -754,14 +773,15 @@ def personalized_pagerank(
     if iters < 1:
         raise ValueError(f"iters must be >= 1, got {iters}")
     ex = executor or get_default_executor()
-    n, m = csr.num_nodes, csr.num_edges
-    from repro.core.graph import segment_ids_from_offsets
-
-    src = segment_ids_from_offsets(csr.offsets, m)
+    hs = ex.host_streams
+    n = csr.num_nodes
+    deg_host = np.diff(np.asarray(csr.offsets))
+    # edge -> owning row, on the host (the CSR is sorted by row)
+    with hs.timed():
+        owner = np.repeat(np.arange(n, dtype=np.int32), deg_host)
+    src = hs.upload(owner)
     dst = csr.neighs
-    outdeg = jnp.maximum(
-        csr.offsets[1:] - csr.offsets[:-1], 1
-    ).astype(jnp.float32)
+    outdeg = jnp.asarray(np.maximum(deg_host, 1).astype(np.float32))
 
     single = sources is None or np.ndim(sources) == 0
     if sources is None:
@@ -772,18 +792,13 @@ def personalized_pagerank(
             raise ValueError("personalized_pagerank needs >= 1 source")
         if not ((srcs >= 0) & (srcs < n)).all():
             raise ValueError(f"sources outside [0, {n}): {srcs}")
-        restart = (
-            jnp.zeros((n, srcs.size), jnp.float32)
-            .at[jnp.asarray(srcs), jnp.arange(srcs.size)]
-            .set(1.0)
-        )
+        restart = _seeded(hs, srcs, n, 1.0, 0.0, np.float32).T
     red = _LevelReducer(ex, method, None, None)
     ranks = restart
     for it in range(iters):
         red.set_level(it)
-        contrib = ranks / outdeg[:, None]
-        incoming = red(dst, jnp.take(contrib, src, axis=0), out_size=n, op="add")
-        ranks = (1.0 - damp) * restart + damp * incoming
+        incoming = red(dst, _ppr_contrib(ranks, outdeg, src), out_size=n, op="add")
+        ranks = _ppr_restart(restart, incoming, damp)
     out = ranks[:, 0] if single else ranks.T
     return PPRResult(ranks=out, iters=iters, decisions=tuple(red.decisions))
 

@@ -20,19 +20,23 @@ Two kernels, mirroring the paper's §3/§4 contrast:
     read-modify-write flush window is safe because TPU grids execute
     sequentially on a core.
 
-On this CPU-only container both are validated with ``interpret=True``
-against ``ref.py``. Scratch uses VMEM throughout; a production TPU build
-would keep cursors/lengths in SMEM (scalar memory) — noted here because
-interpret mode does not distinguish them.
+Both are validated in interpret mode against ``ref.py`` and have no
+Mosaic lowering (the in-block ranks use ``cumsum``; the appends are
+per-tuple stores at dynamic lanes), so they raise on a TPU rather than
+interpret there (``repro.kernels.interpret_only``). The fused kernels
+(``kernels/fused.py``) show the shape that compiles.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_only
 
 
 # ---------------------------------------------------------------------------
@@ -65,7 +69,7 @@ def counting_positions_pallas(
     *,
     num_bins: int,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Destination position of each element under a stable counting sort
     whose bin b region begins at starts[b]. Padding keys (== num_bins)
@@ -84,7 +88,7 @@ def counting_positions_pallas(
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((keys_p.shape[0],), jnp.int32),
         scratch_shapes=[pltpu.VMEM((num_bins,), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_only("counting_positions_pallas", interpret),
     )(keys_p, starts)
     return pos[:m]
 
@@ -188,7 +192,7 @@ def cobra_binning_pass_pallas(
     num_bins: int,
     block: int = 512,
     cap: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """One COBRA binning pass. keys[i] = bin of tuple (idx[i], val[i]);
     starts (num_bins,) = exclusive bin starts. Returns binned (idx, val),
@@ -229,6 +233,6 @@ def cobra_binning_pass_pallas(
             pltpu.VMEM((num_bins, cap), jnp.int32),  # C-Buffer idx
             pltpu.VMEM((num_bins, cap), jnp.int32),  # C-Buffer val
         ],
-        interpret=interpret,
+        interpret=interpret_only("cobra_binning_pass_pallas", interpret),
     )(keys_p, idx_p, val_p, starts)
     return out_idx[:m], out_val[:m]

@@ -14,10 +14,13 @@ condition (paper Fig. 3, right).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_only
 
 
 def _binread_kernel(idx_ref, val_ref, out_ref, *, bin_range: int):
@@ -38,7 +41,7 @@ def binread_scatter_add_pallas(
     val_padded: jnp.ndarray,  # (B, L, d)
     *,
     bin_range: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Returns (B*bin_range, d): accumulation of val rows at their indices."""
     B, L = idx_padded.shape
@@ -52,5 +55,5 @@ def binread_scatter_add_pallas(
         ],
         out_specs=pl.BlockSpec((bin_range, d), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B * bin_range, d), val_padded.dtype),
-        interpret=interpret,
+        interpret=interpret_only("binread_scatter_add_pallas", interpret),
     )(idx_padded, val_padded)

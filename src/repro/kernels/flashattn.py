@@ -14,11 +14,14 @@ compiles to Mosaic.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _flash_fwd_kernel(
@@ -66,7 +69,7 @@ def flash_attention_pallas(
     causal: bool = True,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     B, H, Sq, hd = q.shape
     KH, Skv = k.shape[1], k.shape[2]
@@ -91,7 +94,7 @@ def flash_attention_pallas(
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
         q.reshape(B * H, Sq, hd),
         k.reshape(B * KH, Skv, hd),

@@ -15,10 +15,13 @@ write of the same output block is well-defined).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _histogram_kernel(keys_ref, out_ref, *, num_bins: int):
@@ -35,7 +38,7 @@ def _histogram_kernel(keys_ref, out_ref, *, num_bins: int):
 
 
 def histogram_pallas(
-    keys: jnp.ndarray, num_bins: int, *, block: int = 2048, interpret: bool = True
+    keys: jnp.ndarray, num_bins: int, *, block: int = 2048, interpret: Optional[bool] = None
 ) -> jnp.ndarray:
     """Count occurrences of each value in [0, num_bins). Out-of-range keys
     (e.g. padding = num_bins) are ignored."""
@@ -49,5 +52,5 @@ def histogram_pallas(
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((num_bins,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((num_bins,), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(keys_p)

@@ -1,14 +1,15 @@
 """Jitted wrappers composing the Pallas kernels into framework ops.
 
-On this CPU container every kernel runs with ``interpret=True`` (the
-kernel body executes as traced JAX ops); on a real TPU backend the same
-call sites compile the Mosaic kernels. ``interpret_default()`` picks per
-backend.
+``interpret=None`` everywhere resolves through
+``repro.kernels.resolve_interpret``: interpret mode off TPU, compiled
+Mosaic on a TPU. The histogram kernel compiles for the chip; the
+positions, C-Buffer binning pass, Bin-Read and row-scatter kernels have
+no Mosaic lowering and raise on a TPU instead of interpreting.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,12 +22,8 @@ from repro.kernels.histogram import histogram_pallas
 from repro.kernels.scatter_rows import scatter_rows_pallas
 
 
-def interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("num_bins", "block", "interpret"))
-def histogram(keys, num_bins: int, block: int = 2048, interpret: bool = True):
+def histogram(keys, num_bins: int, block: int = 2048, interpret: Optional[bool] = None):
     return histogram_pallas(keys, num_bins, block=block, interpret=interpret)
 
 
@@ -34,7 +31,7 @@ def histogram(keys, num_bins: int, block: int = 2048, interpret: bool = True):
     jax.jit, static_argnames=("bin_range", "num_bins", "block", "interpret")
 )
 def pb_binning(
-    idx, val, *, bin_range: int, num_bins: int, block: int = 1024, interpret: bool = True
+    idx, val, *, bin_range: int, num_bins: int, block: int = 1024, interpret: Optional[bool] = None
 ) -> pb_core.Bins:
     """Software-PB binning built from the Pallas histogram + positions
     kernels; the permutation apply is an XLA scatter."""
@@ -61,7 +58,7 @@ def cobra_binning_pass(
     num_bins: int,
     block: int = 512,
     cap: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> pb_core.Bins:
     """One COBRA C-Buffer pass (histogram + flush-managed binning)."""
     keys = (idx // bin_range).astype(jnp.int32)
@@ -88,7 +85,7 @@ def cobra_binning(
     block: int = 512,
     cap: int = 512,
     max_bins_per_pass: int = 4096,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> pb_core.Bins:
     """Hierarchical COBRA binning: one C-Buffer pass per plan level
     (coarse -> fine), the TPU realization of the paper's multi-level
@@ -130,14 +127,14 @@ def padded_bin_layout(bins: pb_core.Bins, num_bins: int, max_per_bin: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bin_range", "interpret"))
-def binread_scatter_add(idx_padded, val_padded, *, bin_range: int, interpret: bool = True):
+def binread_scatter_add(idx_padded, val_padded, *, bin_range: int, interpret: Optional[bool] = None):
     return binread_scatter_add_pallas(
         idx_padded, val_padded, bin_range=bin_range, interpret=interpret
     )
 
 
 @functools.partial(jax.jit, static_argnames=("out_rows", "block", "interpret"))
-def scatter_rows(x, pos, out_rows: int, block: int = 256, interpret: bool = True):
+def scatter_rows(x, pos, out_rows: int, block: int = 256, interpret: Optional[bool] = None):
     return scatter_rows_pallas(x, pos, out_rows, block=block, interpret=interpret)
 
 
@@ -148,7 +145,7 @@ def pb_scatter_add_full(
     *,
     bin_range: int,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """End-to-end PB scatter-add through the kernels: histogram ->
     positions -> row permute -> per-bin MXU apply. Used by the embedding

@@ -11,9 +11,13 @@ disjoint-position writes are well-defined.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_only
 
 
 def _scatter_rows_kernel(pos_ref, x_ref, out_ref):
@@ -40,7 +44,7 @@ def scatter_rows_pallas(
     out_rows: int,
     *,
     block: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """out[pos[i]] = x[i]; unwritten rows are zero."""
     m, d = x.shape
@@ -70,5 +74,5 @@ def scatter_rows_pallas(
         ],
         out_specs=pl.BlockSpec((out_rows, d), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((out_rows, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_only("scatter_rows_pallas", interpret),
     )(pos_p, x_p, zeros)

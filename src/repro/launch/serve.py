@@ -4,8 +4,8 @@ Single-host smoke:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --requests 8
 
 Production pods run the same entrypoint with --mesh prod after
-jax.distributed init (scripts/launch_pod.sh); decode caches shard per
-the seq_kv/batch rules (see distributed/sharding.py).
+``jax.distributed.initialize``; decode caches shard per the seq_kv/batch
+rules (see distributed/sharding.py).
 """
 from __future__ import annotations
 

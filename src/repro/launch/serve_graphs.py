@@ -19,6 +19,7 @@ import argparse
 
 from repro.core.executor import PBExecutor
 from repro.core.graph import graph_suite
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.graph_frontend import (
     Clock,
     FakeClock,
@@ -68,6 +69,7 @@ def main(argv=None):
     ap.add_argument("--no-probe", action="store_true",
                     help="skip compile-warmth probe queries at startup")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     suite = graph_suite(args.scale)
     names = [g.strip() for g in args.graphs.split(",") if g.strip()]

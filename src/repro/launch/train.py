@@ -4,9 +4,10 @@ Single-host examples / tests:
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
       --preset smoke --steps 50 --mesh host:2x2
 
-On a real multi-host pod each host runs this same entrypoint with
-jax.distributed initialized by the cluster scheduler (scripts/launch_pod.sh);
-the mesh spec 'prod' / 'prod-multipod' then spans all processes.
+On a real multi-host pod each host runs this same entrypoint after
+``jax.distributed.initialize`` (coordinator address, process count and
+id passed explicitly); the mesh spec 'prod' / 'prod-multipod' then
+spans all processes.
 
 Features wired in: deterministic restartable data pipeline, async
 checkpointing + auto-resume, straggler detection, heartbeat watchdog,

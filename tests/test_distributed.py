@@ -108,9 +108,10 @@ def test_gradient_compression_error_feedback():
 def test_gpipe_pipeline_matches_sequential():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.distributed.pipeline import gpipe_apply
 
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
         P_st, M, mb, d = 4, 8, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), P_st)
         stage_params = {"w": jnp.stack([jax.random.normal(k, (d, d)) * 0.3 for k in ks])}

@@ -26,12 +26,15 @@ def run_py(body: str, devices: int = 8, timeout: int = 900):
 def test_lower_compile_small_mesh_train_and_decode():
     out = run_py("""
         import jax, jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.configs import get_config
         from repro.configs.registry import ShapeSpec
         from repro.launch.dryrun import lower_cell, device_bytes, abstract_params
         from repro.distributed import sharding as shd
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh(
+            (4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        )
         cfg = get_config("qwen2-1.5b").reduced()
         train = ShapeSpec("t", 64, 8, "train")
         comp = lower_cell(cfg, train, mesh).compile()
@@ -52,10 +55,10 @@ def test_lower_compile_small_mesh_train_and_decode():
 def test_collective_parser_on_real_hlo():
     out = run_py("""
         import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.roofline import collective_bytes_from_hlo
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
         def f(x):
             y = jax.lax.with_sharding_constraint(

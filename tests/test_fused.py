@@ -52,7 +52,7 @@ def test_fused_kernel_matches_scatter_ref(dtype, op):
     n = 777  # non-pow2: ragged final bin
     idx, val = _random_stream(n, 3001, seed=1, dtype=dtype)
     got = cobra_bin_accumulate_pallas(
-        idx, val, num_indices=n, bin_range=100, num_bins=8, op=op,
+        idx, val, num_indices=n, bin_range=100, op=op,
         block=256, cap=512, interpret=True,
     )
     _assert_reduce(got, idx, val, n, op=op)
@@ -62,12 +62,12 @@ def test_fused_kernel_single_bin_and_empty():
     n = 50
     idx, val = _random_stream(n, 400, seed=3)
     got = cobra_bin_accumulate_pallas(
-        idx, val, num_indices=n, bin_range=n, num_bins=1, block=128, cap=512,
+        idx, val, num_indices=n, bin_range=n, block=128, cap=512,
     )
     _assert_reduce(got, idx, val, n)
     empty = cobra_bin_accumulate_pallas(
         jnp.zeros((0,), jnp.int32), jnp.zeros((0,), jnp.float32),
-        num_indices=10, bin_range=5, num_bins=2,
+        num_indices=10, bin_range=5,
     )
     assert empty.shape == (10,) and float(jnp.abs(empty).sum()) == 0.0
 
@@ -87,7 +87,7 @@ def test_fused_rows_kernel_matches_scatter_ref(dtype, op, f_tile):
     else:
         val = jnp.asarray(rng.normal(size=(1500, F)), dtype)
     got = cobra_bin_accumulate_rows_pallas(
-        idx, val, num_indices=n, bin_range=50, num_bins=7, op=op,
+        idx, val, num_indices=n, bin_range=50, op=op,
         block=256, cap=512, f_tile=f_tile, interpret=True,
     )
     _assert_reduce(got, idx, val, n, op=op)
@@ -101,18 +101,17 @@ def test_fused_rows_kernel_edges():
     idx = jnp.asarray(rng.integers(0, n, 300), jnp.int32)
     val = jnp.asarray(rng.normal(size=(300, 1)), jnp.float32)
     got = cobra_bin_accumulate_rows_pallas(
-        idx, val, num_indices=n, bin_range=n, num_bins=1, block=128,
+        idx, val, num_indices=n, bin_range=n, block=128,
         cap=512, interpret=True,
     )
     _assert_reduce(got, idx, val, n)
     empty = cobra_bin_accumulate_rows_pallas(
         jnp.zeros((0,), jnp.int32), jnp.zeros((0, 4), jnp.float32),
-        num_indices=10, bin_range=5, num_bins=2,
+        num_indices=10, bin_range=5,
     )
     assert empty.shape == (10, 4) and float(jnp.abs(empty).sum()) == 0.0
     fless = cobra_bin_accumulate_rows_pallas(
         idx, jnp.zeros((300, 0), jnp.float32), num_indices=n, bin_range=5,
-        num_bins=8,
     )
     assert fless.shape == (n, 0)
 
@@ -121,7 +120,7 @@ def test_fused_kernel_rejects_non_commutative_op():
     with pytest.raises(ValueError, match="commutative"):
         cobra_bin_accumulate_pallas(
             jnp.zeros((4,), jnp.int32), jnp.zeros((4,), jnp.float32),
-            num_indices=4, bin_range=2, num_bins=2, op="concat",
+            num_indices=4, bin_range=2, op="concat",
         )
 
 
@@ -212,6 +211,93 @@ def test_reduce_stream_smoke_suite_equivalence():
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), atol=1e-3, err_msg=f"{name}/{method}"
             )
+
+
+@pytest.mark.parametrize(
+    "use_pallas, shape, batched, want",
+    [
+        (False, (4000,), False, "jnp"),  # interpret mode: the jnp sweep
+        (True, (4000,), False, "pallas"),  # the (interpreted) C-Buffer kernel
+        (True, (4000, 1), False, "pallas"),  # width-1 rows run the scalar kernel
+        (True, (4000,), True, "jnp"),  # lanes off TPU: use_pallas never interprets them
+    ],
+)
+def test_fused_decisions_name_their_realization(tmp_path, use_pallas, shape, batched, want):
+    """Every fused decision-log entry says which realization ran, and
+    that realization's answer matches the oracle."""
+    ex = PBExecutor(use_pallas=use_pallas, cache_dir=str(tmp_path))
+    n = 300
+    idx, val = _random_stream(n, shape[0], seed=29)
+    val = val.reshape(shape)
+    if batched:
+        got = ex.reduce_streams(idx[None], val[None], out_size=n)[0]
+    else:
+        got = ex.reduce_stream(idx, val, out_size=n)
+    entry = ex.decision_log[-1]
+    assert entry["method"] == "fused" and entry["realization"] == want
+    _assert_reduce(got, idx, val, n)
+
+
+@pytest.mark.parametrize("op", ["add", "max"])
+def test_rows_wider_than_the_budget_run_one_column_at_a_time(tmp_path, op):
+    """F=3 rows on a 2 MiB fast level: a 128-lane row tile does not fit,
+    one column does, so the decision is fused at f_tile 1 and the scalar
+    kernel (interpreted here) sweeps each column; the answer is exact."""
+    from repro.core.plan import HardwareModel, fused_fits
+
+    hw = HardwareModel(
+        name="2mib", fast_levels=(2 << 20,), cbuffer_bytes=4096,
+        dram_bandwidth=819e9, fast_bandwidth=20e12,
+    )
+    n, m, feat = 300, 4000, 3
+    assert fused_fits(hw, n, f_tile=1) and not fused_fits(hw, n, f_tile=feat)
+    ex = PBExecutor(hw=hw, use_pallas=True, cache_dir=str(tmp_path))
+    rng = np.random.default_rng(7)
+    idx = jnp.asarray(rng.integers(0, n, m), jnp.int32)
+    val = jnp.asarray(rng.integers(-50, 50, (m, feat)), jnp.int32)
+    got = ex.reduce_stream(idx, val, out_size=n, op=op)
+    entry = ex.decision_log[-1]
+    assert (entry["method"], entry["f_tile"], entry["realization"]) == ("fused", 1, "pallas")
+    _assert_reduce(got, idx, val, n, op=op)
+
+
+@pytest.mark.parametrize("op", ["add", "min", "max"])
+def test_batched_kernel_lanes_match_vmapped_sweep(op, monkeypatch):
+    """The batched program a TPU runs for a fused reduce that fits — the
+    C-Buffer kernel once per lane — equals the vmapped jnp sweep."""
+    from repro.core import executor as executor_mod
+    from repro.core.executor import _jitted_reduce_batched
+    from repro.core.plan import FUSED_BIN_RANGE, HardwareModel
+
+    n, lanes, m = 300, 3, 700
+    rng = np.random.default_rng(31)
+    idx = jnp.asarray(rng.integers(0, n, (lanes, m)), jnp.int32)
+    val = jnp.asarray(rng.integers(-50, 50, (lanes, m)), jnp.int32)
+    common = (n, FUSED_BIN_RANGE, None, "fused", op, 2048, True, None)
+    budget = HardwareModel.tpu_v5e().fast_levels[-1]
+    swept = _jitted_reduce_batched(*common, budget)(idx, val)
+    # realize every lane as the kernel, in interpret mode here (a distinct
+    # budget keys a fresh program)
+    monkeypatch.setattr(executor_mod, "fused_realization", lambda *a, **k: "pallas")
+    kernel = _jitted_reduce_batched(*common, budget - 1)(idx, val)
+    _jitted_reduce_batched.cache_clear()
+    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(swept))
+
+
+def test_hardware_model_by_device_kind_and_interpret_resolution():
+    """A TPU's model comes from its device_kind (unknown kinds raise),
+    and interpret mode is resolved in one place: interpret off TPU, and a
+    kernel without a Mosaic lowering refuses to compile."""
+    from repro.core.plan import HardwareModel
+    from repro.kernels import interpret_only, resolve_interpret
+
+    assert HardwareModel.for_device_kind("TPU v5 lite") == HardwareModel.tpu_v5e()
+    with pytest.raises(ValueError, match="device_kind"):
+        HardwareModel.for_device_kind("TPU v99")
+    assert resolve_interpret(None) is True  # CPU backend
+    assert resolve_interpret(False) is False  # lowering for a described TPU
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        interpret_only("scatter_rows_pallas", False)
 
 
 def test_reduce_decisions_cached_separately_from_binning():
