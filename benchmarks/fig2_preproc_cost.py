@@ -65,8 +65,8 @@ def run() -> Rows:
 
         # (b) every reorder variant through the pipeline + amortization.
         # The pipeline warms each stage itself (an untimed first pass):
-        # ``seconds`` is steady-state, the compile cost is reported
-        # separately — amortization points are no longer compile-skewed.
+        # ``seconds`` is steady-state, the warmup pass is reported
+        # separately (warmup_us) — amortization points are not compile-skewed.
         for variant in REORDER_VARIANTS:
             pipe = PreprocessPipeline(variant=variant, build_method="auto")
             res = pipe.run(g)
@@ -77,7 +77,7 @@ def run() -> Rows:
             rows.add(
                 f"fig2b/preproc/{variant}/{name}",
                 rep.total_seconds * 1e6,
-                f"{stage_us} compile_us={rep.total_compile_seconds*1e6:.0f} "
+                f"{stage_us} warmup_us={rep.total_warmup_seconds*1e6:.0f} "
                 f"modeled_bytes={rep.total_modeled_bytes:.3g} "
                 f"decisions={len(rep.decisions())}",
             )

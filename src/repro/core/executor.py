@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import pb
+from repro.core import pb, spans
 from repro.core.cobra import hierarchical_binning
 from repro.core.plan import (
     FUSED_BIN_RANGE,
@@ -615,9 +615,10 @@ class HostStreamStats:
     """The host share of the streams a traversal hands its executor,
     summed over calls until ``reset``: seconds spent expanding frontiers
     and building level streams in host numpy, uploads to the device
-    included; the bytes uploaded; the levels expanded. Reading a level's
-    result back to the host waits on the device, so it is not in
-    ``seconds``."""
+    included (the ``traversal.host_stream`` spans); the bytes uploaded
+    and the levels expanded (those spans' ``upload_bytes`` and
+    ``levels`` counters). Reading a level's result back to the host
+    waits on the device, so it is not in ``seconds``."""
 
     def __init__(self):
         self.reset()
@@ -629,15 +630,18 @@ class HostStreamStats:
 
     @contextlib.contextmanager
     def timed(self):
-        t0 = time.perf_counter()
+        sp = spans.span("traversal.host_stream")
         try:
-            yield
+            with sp:
+                yield sp
         finally:
-            self.seconds += time.perf_counter() - t0
+            self.seconds += sp.seconds
+            self.upload_bytes += sp.counters.get("upload_bytes", 0)
+            self.levels += sp.counters.get("levels", 0)
 
     def upload(self, a: np.ndarray) -> jnp.ndarray:
         with self.timed():
-            self.upload_bytes += a.nbytes
+            spans.count("upload_bytes", a.nbytes)
             # waited for, so the copy is in ``seconds``
             return jnp.asarray(a).block_until_ready()
 
@@ -1345,7 +1349,15 @@ class PBExecutor:
 
         ``method=None`` (or "auto") consults ``decide``; an explicit
         method skips planning but still routes through the shared core.
+        An eager call is the span ``pb.bin_stream``.
         """
+        with spans.span(
+            "pb.bin_stream", over=(indices,), stream_len=int(indices.shape[0]),
+            num_indices=num_indices,
+        ) as sp:
+            return self._bin_stream(indices, values, sp, num_indices, bin_range, method)
+
+    def _bin_stream(self, indices, values, sp, num_indices, bin_range, method) -> pb.Bins:
         flat = isinstance(values, jnp.ndarray) and values.ndim == 1
         if method in (None, "auto"):
             d = self.decide(
@@ -1357,6 +1369,7 @@ class PBExecutor:
             )
         else:
             d = self._finalize(method, num_indices, bin_range, "caller")
+        sp.set(method=d.method, bin_range=d.bin_range, source=d.source)
         fn = _jitted_binning(
             d.bin_range, d.num_bins, d.method, self.block, self.interpret, d.plan
         )
@@ -1461,8 +1474,21 @@ class PBExecutor:
         BENCH_smoke.json can attribute method choices to mutation
         traffic. Forced-method update calls still log (source="caller"):
         the mutation trail must be visible even when the caller pinned
-        the method.
+        the method. An eager call is the span ``pb.reduce_stream``.
         """
+        with spans.span(
+            "pb.reduce_stream", over=(indices,), stream_len=int(indices.shape[0]),
+            out_size=out_size, op=op, kind=kind,
+        ) as sp:
+            return self._reduce_stream(
+                indices, values, sp, out_size, op, bin_range, method,
+                sorted_within, in_bounds, kind,
+            )
+
+    def _reduce_stream(
+        self, indices, values, sp, out_size, op, bin_range, method,
+        sorted_within, in_bounds, kind,
+    ) -> jnp.ndarray:
         if op not in REDUCE_OPS:
             raise ValueError(
                 f"reduce_stream only serves commutative reductions {REDUCE_OPS}; "
@@ -1523,6 +1549,7 @@ class PBExecutor:
             indices, values, out_size, d, op=op,
             sorted_within=sorted_within, in_bounds=in_bounds,
         )
+        sp.set(method=d.method, bin_range=d.bin_range, source=d.source)
         fn = _jitted_reduce(
             out_size, d.bin_range, d.num_bins, d.method, op, self.block,
             self.interpret, d.plan, self.use_pallas, sorted_within,

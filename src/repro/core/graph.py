@@ -23,6 +23,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
+
 
 class COO(NamedTuple):
     """Edgelist. src/dst are int32 arrays of equal length (num_edges)."""
@@ -123,20 +125,25 @@ class SlackCSR(NamedTuple):
             raise ValueError(
                 f"headroom/min_slack must be >= 0, got {headroom}/{min_slack}"
             )
-        off = np.asarray(csr.offsets).astype(np.int64)
-        nei = np.asarray(csr.neighs)
-        deg = np.diff(off)
-        cap = deg + np.maximum(min_slack, np.ceil(deg * headroom).astype(np.int64))
-        soff = np.concatenate([[0], np.cumsum(cap)])
-        slab = np.full(int(soff[-1]), TOMBSTONE, np.int32)
-        seg = np.repeat(np.arange(csr.num_nodes), cap)
-        r = np.arange(slab.shape[0]) - soff[seg]
-        occ = r < deg[seg]
-        slab[occ] = nei[(off[seg] + r)[occ]]
+        # the CSR back from the device, then the slab layout in host numpy
+        with span("slack_csr.fetch", fetch_bytes=csr.offsets.nbytes + csr.neighs.nbytes):
+            off = np.asarray(csr.offsets).astype(np.int64)
+            nei = np.asarray(csr.neighs)
+        with span("slack_csr.layout") as sp:
+            deg = np.diff(off)
+            cap = deg + np.maximum(min_slack, np.ceil(deg * headroom).astype(np.int64))
+            soff = np.concatenate([[0], np.cumsum(cap)])
+            slab = np.full(int(soff[-1]), TOMBSTONE, np.int32)
+            seg = np.repeat(np.arange(csr.num_nodes), cap)
+            r = np.arange(slab.shape[0]) - soff[seg]
+            occ = r < deg[seg]
+            slab[occ] = nei[(off[seg] + r)[occ]]
+            starts, counts = soff.astype(np.int32), deg.astype(np.int32)
+            sp.set(slots=slab.shape[0], upload_bytes=starts.nbytes + slab.nbytes + counts.nbytes)
         return cls(
-            offsets=jnp.asarray(soff.astype(np.int32)),
+            offsets=jnp.asarray(starts),
             neighs=jnp.asarray(slab),
-            counts=jnp.asarray(deg.astype(np.int32)),
+            counts=jnp.asarray(counts),
             num_nodes=csr.num_nodes,
         )
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from repro import compat
 from repro.core.executor import get_default_executor
 from repro.core.graph import COO, CSR, degrees_from_coo, segment_ids_from_offsets
+from repro.core.spans import span
 
 
 class PRResult(NamedTuple):
@@ -155,14 +156,16 @@ def _pr_fused(src, dst, num_nodes, iters, method, bin_range, num_bins, block, pl
     from repro.core.executor import execute_reduce
 
     n = num_nodes
-    outdeg = jnp.maximum(jnp.bincount(src, length=n), 1).astype(jnp.float32)
+    with jax.named_scope("pagerank.outdeg"):
+        outdeg = jnp.maximum(jnp.bincount(src, length=n), 1).astype(jnp.float32)
     ranks = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
 
     def body(_, ranks):
-        contrib = ranks / outdeg
+        with jax.named_scope("pagerank.gather"):
+            contrib = jnp.take(ranks / outdeg, src)
         incoming = execute_reduce(
             dst,
-            jnp.take(contrib, src),
+            contrib,
             out_size=n,
             op="add",
             method=method,
@@ -186,10 +189,16 @@ def pagerank_fused(coo: COO, iters: int = 10, method: str | None = None) -> PRRe
     d = ex.decide_or_forced(
         method, coo.num_nodes, coo.num_edges, jnp.float32, kind="reduce"
     )
-    r = _pr_fused(
-        coo.src, coo.dst, coo.num_nodes, iters, d.method, d.bin_range,
-        d.num_bins, ex.block, d.plan,
-    )
+    # the root span of a job: it covers the dispatch, which returns
+    # before the device has run the iterations
+    with span(
+        "pagerank.run", over=(coo.src,), method=d.method, bin_range=d.bin_range,
+        iters=iters, num_edges=coo.num_edges,
+    ):
+        r = _pr_fused(
+            coo.src, coo.dst, coo.num_nodes, iters, d.method, d.bin_range,
+            d.num_bins, ex.block, d.plan,
+        )
     return PRResult(r, iters)
 
 

@@ -32,7 +32,6 @@ Fig. 2 story plus the amortization point.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import NamedTuple, Optional, Tuple
 
@@ -44,6 +43,7 @@ from repro.core import traffic
 from repro.core.executor import PBExecutor, get_default_executor
 from repro.core.graph import COO, CSR, SlackCSR
 from repro.core.reorder import REORDER_VARIANTS, relabel_coo, reorder_mapping
+from repro.core.spans import span
 
 
 @dataclass(frozen=True)
@@ -51,17 +51,18 @@ class StageReport:
     """One pipeline stage: what ran, how long, what it should have moved."""
 
     name: str
-    seconds: float
+    seconds: float  # the ``preprocess.<name>`` span's duration
     modeled_bytes: float
     # the PBExecutor decision-log entries this stage appended (method,
     # bin_range, source per decided stream) — empty for pure-relabel
     # stages and for caller-forced methods
     decisions: Tuple[dict, ...] = ()
-    # wall-clock of the warmup pass (trace + compile + first run);
-    # ``seconds`` is the steady-state pass that follows. 0.0 when the
-    # pipeline ran cold (warmup=False) — then ``seconds`` includes
-    # compilation and must not feed amortization math.
-    compile_seconds: float = 0.0
+    # wall-clock of the warmup pass (trace + compile + first run), the
+    # ``preprocess.<name>.warmup`` span; ``seconds`` is the steady-state
+    # pass that follows. 0.0 when the pipeline ran cold (warmup=False) —
+    # then ``seconds`` includes compilation and must not feed
+    # amortization math.
+    warmup_seconds: float = 0.0
 
     def describe(self) -> str:
         ms = ", ".join(
@@ -88,8 +89,8 @@ class PreprocessReport:
         return sum(s.seconds for s in self.stages)
 
     @property
-    def total_compile_seconds(self) -> float:
-        return sum(s.compile_seconds for s in self.stages)
+    def total_warmup_seconds(self) -> float:
+        return sum(s.warmup_seconds for s in self.stages)
 
     @property
     def total_modeled_bytes(self) -> float:
@@ -117,7 +118,7 @@ class PreprocessReport:
                 {
                     "name": s.name,
                     "seconds": s.seconds,
-                    "compile_seconds": s.compile_seconds,
+                    "warmup_seconds": s.warmup_seconds,
                     "modeled_bytes": s.modeled_bytes,
                     "decisions": list(s.decisions),
                 }
@@ -171,7 +172,7 @@ class PreprocessPipeline:
     warmup:       run each stage once untimed before the timed pass
                   (default True): ``StageReport.seconds`` is then
                   steady-state and the warmup's wall-clock lands in
-                  ``StageReport.compile_seconds``. False times stages
+                  ``StageReport.warmup_seconds``. False times stages
                   cold — only for measuring compile cost itself.
     slack_headroom: when set, a final "slack" stage re-slacks the built
                   CSR into the mutable ``SlackCSR`` layout (DESIGN.md
@@ -226,45 +227,56 @@ class PreprocessPipeline:
     # -- stage driver ------------------------------------------------------
 
     def _run_stage(self, stages, ex, name, modeled_bytes, fn):
-        """Time one stage (synchronized), capturing the executor
-        decisions it takes via an uncapped sink — the shared
-        ``decision_log`` saturates at its cap, this channel never
-        drops a stage's entries.
+        """Time one stage (synchronized) as the span
+        ``preprocess.<name>``, capturing the executor decisions it takes
+        via an uncapped sink — the shared ``decision_log`` saturates at
+        its cap, this channel never drops a stage's entries.
 
         Stages used to be timed cold, so first-run numbers included JIT
         trace/compile and skewed the fig2 amortization points. With
-        ``warmup`` (the default) an untimed first pass absorbs
-        compilation — its wall-clock is reported separately as
-        ``compile_seconds`` — and ``seconds`` is the steady-state pass
-        the amortization math wants. The sink is attached only around
-        the timed pass so decisions aren't double-counted (``decide``
-        runs on every invocation)."""
-        compile_s = 0.0
+        ``warmup`` (the default) an untimed first pass, the span
+        ``preprocess.<name>.warmup``, absorbs compilation — reported
+        separately as ``warmup_seconds`` — and ``seconds`` is the
+        steady-state pass the amortization math wants. The sink is
+        attached only around the timed pass so decisions aren't
+        double-counted (``decide`` runs on every invocation)."""
+        warmup_s = 0.0
         if self.warmup:
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            compile_s = time.perf_counter() - t0
+            with span(f"preprocess.{name}.warmup") as sp:
+                jax.block_until_ready(fn())
+            warmup_s = sp.seconds
         sink: list = []
         ex.add_decision_sink(sink)
-        t0 = time.perf_counter()
         try:
-            out = fn()
-            jax.block_until_ready(out)
+            with span(f"preprocess.{name}", stage=name) as sp:
+                out = fn()
+                jax.block_until_ready(out)
+                sp.set(decisions=";".join(
+                    f"{d['method']}@r{d['bin_range']}" for d in sink
+                ))
         finally:
             ex.remove_decision_sink(sink)
-        dt = time.perf_counter() - t0
         stages.append(
             StageReport(
                 name=name,
-                seconds=dt,
+                seconds=sp.seconds,
                 modeled_bytes=modeled_bytes,
                 decisions=tuple(sink),
-                compile_seconds=compile_s,
+                warmup_seconds=warmup_s,
             )
         )
         return out
 
     def run(self, coo: COO) -> PreprocessResult:
+        """Every stage once, under the root span ``preprocess.run``."""
+        with span(
+            "preprocess.run", over=(coo.src,), variant=self.variant,
+            build_method=self.build_method, num_nodes=coo.num_nodes,
+            num_edges=coo.num_edges,
+        ):
+            return self._run(coo)
+
+    def _run(self, coo: COO) -> PreprocessResult:
         ex = self.executor or get_default_executor()
         n, m = coo.num_nodes, coo.num_edges
         stages: list = []

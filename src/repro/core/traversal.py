@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.executor import HostStreamStats, PBExecutor, get_default_executor
 from repro.core.graph import CSR
 
@@ -119,7 +120,7 @@ def _expand_frontiers(
             )
             src[q, :total] = np.repeat(f, deg)
             ok[q, :total] = True
-        stats.levels += 1
+        spans.count("levels")
         return _Edges(neighs_host[pos], pos, src, ok)
 
 

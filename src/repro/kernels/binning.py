@@ -81,6 +81,7 @@ def counting_positions_pallas(
     pos = pl.pallas_call(
         functools.partial(_positions_kernel, num_bins=num_bins),
         grid=grid,
+        name="pb_counting_positions",
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((num_bins,), lambda i: (0,)),
@@ -213,6 +214,7 @@ def cobra_binning_pass_pallas(
     out_idx, out_val = pl.pallas_call(
         functools.partial(_cobra_kernel, num_bins=num_bins, cap=cap, nblocks=nblocks),
         grid=grid,
+        name="pb_cobra_binning_pass",
         in_specs=[
             pl.BlockSpec((block,), in_map),
             pl.BlockSpec((block,), in_map),

@@ -49,6 +49,7 @@ def binread_scatter_add_pallas(
     return pl.pallas_call(
         functools.partial(_binread_kernel, bin_range=bin_range),
         grid=(B,),
+        name="pb_binread_scatter_add",
         in_specs=[
             pl.BlockSpec((1, L), lambda b: (b, 0)),
             pl.BlockSpec((1, L, d), lambda b: (b, 0, 0)),

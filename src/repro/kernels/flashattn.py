@@ -82,6 +82,7 @@ def flash_attention_pallas(
             _flash_fwd_kernel, kv_block=kv_block, causal=causal, seq_kv=Skv
         ),
         grid=grid,
+        name="pb_flash_attention_fwd",
         in_specs=[
             pl.BlockSpec((1, q_block, hd), lambda b, h, qi: (b * H + h, qi, 0)),
             pl.BlockSpec((1, Skv, hd), lambda b, h, qi: (b * KH + h // G, 0, 0)),

@@ -381,6 +381,7 @@ def cobra_bin_accumulate_rows_pallas(
         # feature axis OUTERMOST: the whole stream is swept per F-tile,
         # plus one trailing drain step per tile
         grid=(n_ftiles, nblocks + 1),
+        name="pb_fused_reduce_rows",
         in_specs=[
             pl.BlockSpec((block,), stream_map, memory_space=pltpu.SMEM),
             pl.BlockSpec((block, ft), lambda f, i: (jnp.minimum(i, nblocks - 1), f)),
@@ -445,6 +446,7 @@ def cobra_bin_accumulate_pallas(
             op=op,
         ),
         grid=(nblocks + 1,),  # +1 drain step
+        name="pb_fused_reduce",
         in_specs=[
             pl.BlockSpec((block,), in_map, memory_space=pltpu.SMEM),
             pl.BlockSpec((block,), in_map, memory_space=pltpu.SMEM),

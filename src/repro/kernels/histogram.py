@@ -49,6 +49,7 @@ def histogram_pallas(
     return pl.pallas_call(
         functools.partial(_histogram_kernel, num_bins=num_bins),
         grid=grid,
+        name="pb_histogram",
         in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
         out_specs=pl.BlockSpec((num_bins,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((num_bins,), jnp.int32),

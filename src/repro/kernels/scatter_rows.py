@@ -67,6 +67,7 @@ def scatter_rows_pallas(
     return pl.pallas_call(
         kernel,
         grid=(nblocks,),
+        name="pb_scatter_rows",
         in_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
             pl.BlockSpec((block, d), lambda i: (i, 0)),

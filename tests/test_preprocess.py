@@ -27,6 +27,7 @@ from repro.core import (
     csr_equal_as_sets,
     get_default_executor,
     set_default_executor,
+    spans,
     transpose_coo,
 )
 from repro.core.graph import degrees_from_coo, gen_powerlaw, gen_uniform
@@ -165,6 +166,62 @@ def test_pipeline_without_csc():
     res = PreprocessPipeline("identity", "baseline", with_csc=False).run(_graph())
     assert res.csc is None
     assert [s.name for s in res.report.stages][-1] == "build_csr"
+
+
+@pytest.mark.parametrize("warmup", [True, False])
+def test_stage_seconds_are_their_spans(warmup):
+    spans.reset()
+    res = PreprocessPipeline(
+        "degree_sort", "auto", with_csc=False, warmup=warmup
+    ).run(_graph())
+    recorded = spans.recorded()
+    (root,) = [s for s in recorded if s.name == "preprocess.run"]
+    assert root.parent_id is None
+    assert root.attrs == {
+        "variant": "degree_sort", "build_method": "auto",
+        "num_nodes": 512, "num_edges": res.report.num_edges,
+    }
+    by_name = {s.name: s for s in recorded}
+    for stage in res.report.stages:
+        sp = by_name[f"preprocess.{stage.name}"]
+        assert stage.seconds == sp.seconds
+        assert sp.parent_id == root.span_id == sp.root_id
+        assert sp.attrs["stage"] == stage.name
+        assert sp.attrs["decisions"] == ";".join(
+            f"{d['method']}@r{d['bin_range']}" for d in stage.decisions
+        )
+        warm = by_name.get(f"preprocess.{stage.name}.warmup")
+        if warmup:
+            assert stage.warmup_seconds == warm.seconds > 0
+        else:
+            assert warm is None and stage.warmup_seconds == 0.0
+    assert res.report.total_warmup_seconds == sum(
+        s.warmup_seconds for s in res.report.stages
+    )
+    assert "warmup_seconds" in res.report.as_dict()["stages"][0]
+
+
+def test_slack_stage_spans_split_fetch_and_layout():
+    spans.reset()
+    res = PreprocessPipeline(
+        "degree_sort", "auto", with_csc=False, warmup=False, slack_headroom=0.25
+    ).run(_graph())
+    recorded = spans.recorded()
+    (slack,) = [s for s in recorded if s.name == "preprocess.slack"]
+    children = {s.name: s for s in recorded if s.parent_id == slack.span_id}
+    assert set(children) == {"slack_csr.fetch", "slack_csr.layout"}
+    fetch, layout = children["slack_csr.fetch"], children["slack_csr.layout"]
+    assert slack.start_ns <= fetch.start_ns <= fetch.end_ns <= layout.start_ns
+    assert layout.end_ns <= slack.end_ns
+    assert fetch.seconds + layout.seconds <= slack.seconds
+    assert res.report.stage("slack").seconds == slack.seconds
+    csr = res.csr
+    assert fetch.attrs == {"fetch_bytes": csr.offsets.nbytes + csr.neighs.nbytes}
+    s = res.slack
+    assert layout.attrs == {
+        "slots": s.capacity,
+        "upload_bytes": s.offsets.nbytes + s.neighs.nbytes + s.counts.nbytes,
+    }
 
 
 def test_pipeline_sharded_8dev():
