@@ -20,6 +20,7 @@ import functools
 import warnings
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -120,31 +121,39 @@ class SlackCSR(NamedTuple):
     ) -> "SlackCSR":
         """Slack layout of ``csr``: per-vertex capacity = degree plus
         ``max(min_slack, ceil(degree * headroom))``, slot order preserved
-        — so ``from_csr(c).to_csr()`` reproduces ``c`` exactly."""
+        — so ``from_csr(c).to_csr()`` reproduces ``c`` exactly.
+
+        Only the offsets come back to the host: the capacities are
+        n-sized float64/int64 numpy (float32 would round ``ceil(50 *
+        0.3)`` up to 16), and the arc- and slot-sized work runs on the
+        device (``_arc_slots``, ``_slab_scatter``), over the neighbours
+        where they already are."""
         if headroom < 0 or min_slack < 0:
             raise ValueError(
                 f"headroom/min_slack must be >= 0, got {headroom}/{min_slack}"
             )
-        # the CSR back from the device, then the slab layout in host numpy
-        with span("slack_csr.fetch", fetch_bytes=csr.offsets.nbytes + csr.neighs.nbytes):
+        with span("slack_csr.fetch", fetch_bytes=csr.offsets.nbytes):
             off = np.asarray(csr.offsets).astype(np.int64)
-            nei = np.asarray(csr.neighs)
         with span("slack_csr.layout") as sp:
             deg = np.diff(off)
-            cap = deg + np.maximum(min_slack, np.ceil(deg * headroom).astype(np.int64))
-            soff = np.concatenate([[0], np.cumsum(cap)])
-            slab = np.full(int(soff[-1]), TOMBSTONE, np.int32)
-            seg = np.repeat(np.arange(csr.num_nodes), cap)
-            r = np.arange(slab.shape[0]) - soff[seg]
-            occ = r < deg[seg]
-            slab[occ] = nei[(off[seg] + r)[occ]]
-            starts, counts = soff.astype(np.int32), deg.astype(np.int32)
-            sp.set(slots=slab.shape[0], upload_bytes=starts.nbytes + slab.nbytes + counts.nbytes)
+            extra = np.maximum(min_slack, np.ceil(deg * headroom))
+            total = off[-1] + extra.sum()
+            if not total < 2**31:
+                raise ValueError(
+                    f"slack layout needs {total:.0f} slots; int32 slot ids "
+                    f"hold fewer than 2**31 (headroom {headroom}, "
+                    f"min_slack {min_slack})"
+                )
+            slack = extra.astype(np.int32)
+            starts, slots, counts = _arc_slots(
+                csr.offsets, slack, num_arcs=csr.num_edges
+            )
+            slab = jax.block_until_ready(
+                _slab_scatter(slots, csr.neighs, total=int(total))
+            )
+            sp.set(slots=int(total), upload_bytes=slack.nbytes)
         return cls(
-            offsets=jnp.asarray(starts),
-            neighs=jnp.asarray(slab),
-            counts=jnp.asarray(counts),
-            num_nodes=csr.num_nodes,
+            offsets=starts, neighs=slab, counts=counts, num_nodes=csr.num_nodes
         )
 
     def to_csr(self) -> CSR:
@@ -171,6 +180,82 @@ class SlackCSR(NamedTuple):
             dst=jnp.asarray(nei[live].astype(np.int32)),
             num_nodes=self.num_nodes,
         )
+
+
+@functools.partial(jax.jit, static_argnames="num_arcs")
+def _arc_slots(offsets, slack, *, num_arcs: int):
+    """(starts, slot of every arc, counts) of the slack layout on the
+    device, given each vertex's slack (capacity less degree).
+
+    Arc ``i`` of vertex ``v`` lands in slot ``i + sum(slack[:v])``: a
+    marker of ``slack[v - 1]`` at ``v``'s first arc, summed up the arcs,
+    gives that shift without gathering a per-vertex table at every arc
+    (``add`` stacks the markers of zero-degree runs; ``drop`` discards
+    those of vertices whose arcs start at the end). Every prefix is a
+    slot id, so the sums are exact below 2**31 slots."""
+    n = slack.shape[0]
+    offsets = offsets.astype(jnp.int32)
+    counts = offsets[1:] - offsets[:-1]
+    marks = jnp.zeros((num_arcs,), jnp.int32).at[offsets[1:n]].add(
+        slack[: n - 1],
+        mode="drop",
+        # sorted-ok: CSR offsets never decrease
+        indices_are_sorted=True,
+    )
+    slots = jnp.arange(num_arcs, dtype=jnp.int32) + _prefix_sum(marks)
+    starts = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), _prefix_sum(counts + slack)]
+    )
+    return starts, slots, counts
+
+
+_SCAN_ROW = 128
+
+
+def _prefix_sum(x):
+    """Inclusive prefix sum of a non-negative int32 vector whose total
+    is below 2**31, as matmuls: ``jnp.cumsum`` lowers to a reduce-window
+    whose compile time for a v5e grows with the length (30 s at 2**20
+    elements), where a dot compiles in well under a second at any size.
+
+    Each row of 128 is summed by a matmul with an upper-triangular
+    matrix of ones, one byte of the values at a time: the products are
+    exact in bfloat16 and a row's byte sums (below 2**15) in float32,
+    and the shifted bytes add up modulo 2**32 to the exact total. The
+    row totals are summed the same way, one level up."""
+    m = x.shape[0]
+    rows = -(-m // _SCAN_ROW)
+    x = jnp.pad(x, (0, rows * _SCAN_ROW - m)).reshape(rows, _SCAN_ROW)
+    k = jnp.arange(_SCAN_ROW)
+    upper = (k[:, None] <= k[None, :]).astype(jnp.bfloat16)
+    within = sum(
+        jnp.dot(
+            ((x >> shift) & 0xFF).astype(jnp.bfloat16),
+            upper,
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)
+        << shift
+        for shift in (0, 8, 16, 24)
+    )
+    if rows > 1:
+        totals = within[:, -1]
+        within = within + (_prefix_sum(totals) - totals)[:, None]
+    return within.reshape(-1)[:m]
+
+
+@functools.partial(jax.jit, static_argnames="total")
+def _slab_scatter(slots, neighs, *, total: int):
+    """The ``total``-slot slab: each arc in its slot, ``TOMBSTONE``
+    elsewhere. A program of its own because the slot total changes with
+    every graph, where ``_arc_slots``'s shapes are only n and the arc
+    count: graphs of one size share that program, and each slot total
+    compiles only this scatter."""
+    return jnp.full((total,), TOMBSTONE, jnp.int32).at[slots].set(
+        neighs.astype(jnp.int32),
+        # sorted-ok: slots is the arc index plus a running sum of slack >= 0
+        indices_are_sorted=True,
+        unique_indices=True,
+    )
 
 
 def degrees_from_coo(coo: COO, *, by: str = "src") -> jnp.ndarray:

@@ -221,9 +221,9 @@ def build_slack_csr(
 ) -> SlackCSR:
     """EL->SlackCSR: the mutable layout ``core.updates`` edits in place
     (DESIGN.md §15). The packed CSR comes out of the same PB build as
-    ``build_csr``; the re-slack is one gather into a slab with
-    ``headroom`` fractional (min ``min_slack`` absolute) spare capacity
-    per vertex."""
+    ``build_csr``; the re-slack (``SlackCSR.from_csr``) is one device
+    program that scatters the arcs into slabs with ``headroom``
+    fractional (min ``min_slack`` absolute) spare capacity per vertex."""
     csr = build_csr(
         coo, method=method, bin_range=bin_range, block=block, degrees=degrees
     )

@@ -30,11 +30,13 @@ and the one-time cache-save warning naming the unwritable path.
 """
 import warnings
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (
     COO,
+    CSR,
     PBExecutor,
     SlackCSR,
     TOMBSTONE,
@@ -87,6 +89,104 @@ def test_slackcsr_roundtrip_is_exact(name, headroom):
         np.asarray(s.live_degrees()), np.diff(np.asarray(csr.offsets))
     )
     assert 0.0 < s.slack_fraction < 1.0
+
+
+def _numpy_slack_layout(off, nei, headroom, min_slack):
+    """The slab layout written out in host numpy, slot by slot."""
+    off = np.asarray(off).astype(np.int64)
+    nei = np.asarray(nei)
+    deg = np.diff(off)
+    cap = deg + np.maximum(min_slack, np.ceil(deg * headroom).astype(np.int64))
+    soff = np.concatenate([[0], np.cumsum(cap)])
+    slab = np.full(int(soff[-1]), TOMBSTONE, np.int32)
+    seg = np.repeat(np.arange(deg.shape[0]), cap)
+    r = np.arange(slab.shape[0]) - soff[seg]
+    occ = r < deg[seg]
+    slab[occ] = nei[(off[seg] + r)[occ]]
+    return soff, slab, deg
+
+
+def _hand_csr(degrees):
+    """A CSR with the given out-degrees; neighbour ids spread over 0..n-1."""
+    deg = np.asarray(degrees, np.int64)
+    off = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    nei = (np.arange(int(off[-1])) * 7 % len(deg)).astype(np.int32)
+    return CSR(offsets=jnp.asarray(off), neighs=jnp.asarray(nei), num_nodes=len(deg))
+
+
+_HAND = {
+    "zero_degree_first": [0, 0, 3, 1, 2],
+    "zero_degree_last": [2, 5, 1, 0, 0, 0],
+    "zero_degree_runs": [0, 4, 0, 0, 0, 2, 0, 1, 0, 0, 3, 0],
+    "no_arcs": [0, 0, 0, 0],
+    "single_vertex": [6],
+    "single_isolated_vertex": [0],
+    "deg50": [50],
+}
+_LAYOUT_CASES = [
+    (name, headroom, min_slack)
+    for name in sorted(SUITE)
+    for headroom in (0.0, 0.25, 0.3, 1.0)
+    for min_slack in (0, 4)
+] + [
+    (f"hand:{name}", headroom, min_slack)
+    for name in _HAND
+    for headroom, min_slack in ((0.0, 0), (0.3, 0), (0.25, 4))
+]
+
+
+@pytest.mark.parametrize("name,headroom,min_slack", _LAYOUT_CASES)
+def test_slackcsr_device_layout_matches_numpy(name, headroom, min_slack):
+    """``from_csr``'s device layout equals the host numpy layout entry by
+    entry, with the capacities of float64 math (float32 would give
+    ``ceil(50 * 0.3) = 16``)."""
+    if name.startswith("hand:"):
+        csr = _hand_csr(_HAND[name[5:]])
+    else:
+        csr = build_csr(SUITE[name])
+    s = SlackCSR.from_csr(csr, headroom=headroom, min_slack=min_slack)
+    want = _numpy_slack_layout(csr.offsets, csr.neighs, headroom, min_slack)
+    for got, exp in zip((s.offsets, s.neighs, s.counts), want):
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), exp)
+    assert s.num_nodes == csr.num_nodes
+    if name == "hand:deg50" and (headroom, min_slack) == (0.3, 0):
+        assert np.asarray(s.offsets).tolist() == [0, 65]
+
+
+@pytest.mark.parametrize(
+    "length,total",
+    [
+        (0, 0),
+        (1, 2**31 - 1),
+        (127, 1000),
+        (128, 2**31 - 1),
+        (129, 2**31 - 1),
+        (128 * 128 + 1, 2**31 - 1),
+        (128**3 + 5, 2**31 - 1),
+    ],
+)
+def test_device_prefix_sum_is_exact(length, total):
+    """The layout's int32 prefix sums (bytes through bfloat16 matmuls)
+    equal numpy's across row and level boundaries, with every byte of
+    the values in use and the running sum up to 2**31 - 1."""
+    rng = np.random.default_rng(length)
+    x = np.zeros(length, np.int64)
+    if length:
+        cuts = np.sort(rng.integers(0, total + 1, length - 1))
+        x = np.diff(np.concatenate([[0], cuts, [total]]))
+    got = np.asarray(graph_mod._prefix_sum(jnp.asarray(x.astype(np.int32))))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, np.cumsum(x))
+
+
+def test_slackcsr_refuses_2_31_slots():
+    csr = _hand_csr([1000])
+    with pytest.raises(ValueError, match="2\\*\\*31"):
+        SlackCSR.from_csr(csr, headroom=3e6, min_slack=0)
+    # the limit is on the total: two empty slabs of 2**30 slots reach it
+    with pytest.raises(ValueError, match="2\\*\\*31"):
+        SlackCSR.from_csr(_hand_csr([0, 0]), headroom=0.0, min_slack=2**30)
 
 
 def test_slackcsr_rejects_negative_headroom():
