@@ -17,9 +17,10 @@ import os
 import sys
 
 
-def readings(cell_name: str, program_seeds, control_seeds, config=None):
-    """Yield ``(kind, seed, numbers)`` for every seed; ``config`` replaces
-    the cell's configuration (a small graph, in tests)."""
+def readings(cell_name: str, program_seeds, control_seeds, config=None, devices=None):
+    """Yield ``(kind, seed, numbers)`` for every seed, the graph made on
+    ``devices`` (the cell's chips); ``config`` replaces the cell's
+    configuration (a small graph, in tests)."""
     import numpy as np
 
     from bench import gen, run
@@ -30,7 +31,7 @@ def readings(cell_name: str, program_seeds, control_seeds, config=None):
     jobs_mod = run.job_module(traffic["job"])
     for kind, seeds in (("program", program_seeds), ("control", control_seeds)):
         for seed in seeds:
-            coo = gen.generate(config, gen.seed_key(seed))
+            coo = gen.generate(config, gen.seed_key(seed), devices)
             src, dst = np.asarray(coo.src), np.asarray(coo.dst)
             if kind == "program":
                 job = jobs_mod.Job(coo, traffic, np.random.default_rng(seed))
@@ -60,9 +61,9 @@ def main(argv=None) -> int:
     spec = run.load_spec()
     cell, _, _ = run.cell_files(spec, args.workload)
     run.enable_compile_cache()
-    run.tpu_devices(int(cell["chips"]))
+    devices = run.tpu_devices(int(cell["chips"]))
     for kind, seed, numbers in readings(
-        args.workload, seeds(args.program_seeds), seeds(args.control_seeds)
+        args.workload, seeds(args.program_seeds), seeds(args.control_seeds), devices=devices
     ):
         print(json.dumps({"cell": args.workload, "kind": kind, "seed": seed, "numbers": numbers}), flush=True)
     return 0

@@ -3,14 +3,14 @@ C-Buffer kernel's events in the traced window over the tuples it reduced
 there (edges x iterations of the traced jobs, counted by the benchmark).
 Layer: the fused kernel (``kernels/fused.py``). Moves ``edges_per_s``.
 
-The kernel is matched by the name the trace gives its events on a v5e
-today: the program's ``pallas_call`` carries no name, so its events are
-HLO custom calls named after the enclosing function (``%closed_call.4``,
-``%f.1``) whose target is ``tpu_custom_call``: ``KERNEL``. On the
-PageRank path the fused kernel is the only Mosaic kernel. A cell whose
+The kernel is matched by its own name: its ``pallas_call`` is named
+``pb_fused_reduce``, so its events are HLO custom calls whose name
+starts with ``KERNEL`` (``%pb_fused_reduce.3``). The exchange's, the
+binning's and the row kernel's Mosaic calls (``%pb_fused_reduce_rows.1``
+among them) have names of their own and are not counted. A cell whose
 reduces take another path has no such events and reports nothing.
 """
-KERNEL = 'custom_call_target="tpu_custom_call"'
+KERNEL = "%pb_fused_reduce."
 
 
 def read(ctx):

@@ -10,7 +10,7 @@ whose ``job`` names a job kind (``bench/jobs/<kind>.py``). A run:
   1. keeps JAX's persistent compilation cache at ``$JAX_COMPILATION_CACHE_DIR``,
      else at ``<checkout>/.jax_cache``;
   2. fails (exit 2, no result) unless JAX finds a TPU with the cell's chips;
-  3. generates the graph's arcs on the device from ``--seed`` (``bench/gen.py``);
+  3. generates the graph's arcs on the cell's chips from ``--seed`` (``bench/gen.py``);
   4. runs one whole job to warm up every program the window calls;
   5. runs whole jobs, one after another, until ``--seconds`` have passed;
   6. compares what the jobs produced with the plain reference
@@ -196,7 +196,7 @@ def run_cell(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool,
     jobs_mod = job_module(traffic["job"])
     rate = rate_metric(spec, cell_name)
     with jax.profiler.TraceAnnotation("bench.generate"):
-        coo = gen.generate(config, gen.seed_key(seed))
+        coo = gen.generate(config, gen.seed_key(seed), devices)
         jax.block_until_ready((coo.src, coo.dst))
     log(f"{cell_name}: {config['name']} n={coo.num_nodes} m={coo.num_edges} generated")
     job = jobs_mod.Job(coo, traffic, np.random.default_rng(seed))

@@ -88,7 +88,7 @@ def test_recorded_v5e_trace():
     # every op is on one line with no nesting: self time sums to at least
     # the busy union
     assert sum(s.op_seconds.values()) >= s.busy_s * (1 - 1e-9)
-    kernel = s.kernel_seconds('custom_call_target="tpu_custom_call"')
+    kernel = s.kernel_seconds("%_lambda_.1 ")
     assert kernel == pytest.approx(0.006187654, rel=1e-6)
     assert s.top_ops(1)[0][0] == "%_lambda_.1 custom-call[tpu_custom_call]"
     name, secs = s.top_gaps(1)[0]

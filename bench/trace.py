@@ -56,9 +56,10 @@ class Summary:
             by_name[name] += s
         return [[n, s] for n, s in by_name.most_common(k)]
 
-    def kernel_seconds(self, name_part: str) -> float:
-        """Device seconds of every operation whose name contains ``name_part``."""
-        return sum(s for n, s in self.op_seconds.items() if name_part in n)
+    def kernel_seconds(self, prefix: str) -> float:
+        """Device seconds of every operation whose name starts with
+        ``prefix``: the HLO instruction's own name, not an operand's."""
+        return sum(s for n, s in self.op_seconds.items() if n.startswith(prefix))
 
 
 def short_op_name(text: str) -> str:
