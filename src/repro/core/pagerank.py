@@ -193,7 +193,7 @@ def pagerank_fused(coo: COO, iters: int = 10, method: str | None = None) -> PRRe
     # before the device has run the iterations
     with span(
         "pagerank.run", over=(coo.src,), method=d.method, bin_range=d.bin_range,
-        iters=iters, num_edges=coo.num_edges,
+        num_bins=d.num_bins, block=ex.block, iters=iters, num_edges=coo.num_edges,
     ):
         r = _pr_fused(
             coo.src, coo.dst, coo.num_nodes, iters, d.method, d.bin_range,

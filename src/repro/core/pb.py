@@ -255,6 +255,87 @@ def bin_read_scatter_add(
     )
 
 
+# Tuples per row of ``pairwise_segment_sum``'s scan: one 128-lane row.
+_SCAN_ROW = 128
+
+
+def _shift_back(x: jnp.ndarray, s: int, axis: int, fill) -> jnp.ndarray:
+    """``x`` moved ``s`` places up ``axis``, the first ``s`` filled."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (s, 0)
+    head = jax.lax.slice_in_dim(x, 0, x.shape[axis] - s, axis=axis)
+    return jnp.pad(head, pad, constant_values=fill)
+
+
+def _segmented_scan(keys: jnp.ndarray, values: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Inclusive scan of ``values`` along ``axis`` within runs of equal
+    ``keys`` (sorted along that axis, at least 0): Hillis-Steele, each
+    element adds the one ``s`` places back for ``s`` = 1, 2, 4, ... while
+    that one is in its run, so every output is a balanced tree sum."""
+    n = keys.shape[axis]
+    extra = (None,) * (values.ndim - keys.ndim)
+    s = 1
+    while s < n:
+        back = (_shift_back(keys, s, axis, -1) == keys)[(Ellipsis,) + extra]
+        values = values + jnp.where(back, _shift_back(values, s, axis, 0), 0)
+        s *= 2
+    return values
+
+
+def pairwise_segment_sum(
+    indices: jnp.ndarray, values: jnp.ndarray, out_size: int, *, presorted: bool = False
+) -> jnp.ndarray:
+    """``out[i] = sum of values[j] where indices[j] == i``, each index's
+    terms summed along a tree of depth about log2 of the stream length,
+    so that the rounding error of an inexact sum grows with the log of
+    an index's tuple count, not with the count itself.
+
+    A scatter-add accumulates an index's terms one after another and
+    rounds each partial sum: at an index with a million float32 terms the
+    result drifts by parts per thousand. Here the stream is sorted by
+    index (unless the caller says it is, ``presorted``); on a TPU, XLA's
+    own scatter-add sorts its stream first as well. The sorted stream is
+    cut into rows of ``_SCAN_ROW`` tuples, and each row scanned within
+    its runs of one index (``_segmented_scan``). The rows' last elements
+    are scanned the same way across rows, and each row's first run adds
+    what the rows before it hold of that index. The last element of each
+    index's run then holds its sum, and one sorted scatter of those run
+    ends writes the output. Indices outside ``[0, out_size)``, after the
+    usual wrap of negative ones, are dropped, as the scatter drops them.
+    Values are rank-1 or rank-2 (a row per tuple); nothing is computed
+    in another precision.
+    """
+    vshape = value_block_shape(values)
+    m = indices.shape[0]
+    out = jnp.zeros((out_size,) + vshape, values.dtype)
+    if m == 0:
+        return out
+    idx = jnp.where(indices < 0, indices + out_size, indices)
+    idx = jnp.where((idx < 0) | (idx >= out_size), out_size, idx).astype(jnp.int32)
+    if not presorted:
+        if vshape:
+            idx, perm = jax.lax.sort((idx, jnp.arange(m, dtype=jnp.int32)), is_stable=False)
+            values = jnp.take(values, perm, axis=0)
+        else:
+            idx, values = jax.lax.sort((idx, values), is_stable=False)
+    # padding sorts after every index and is dropped with the out-of-range
+    pad = (-m) % _SCAN_ROW
+    if pad:
+        idx = jnp.pad(idx, (0, pad), constant_values=out_size + 1)
+        values = jnp.pad(values, [(0, pad)] + [(0, 0)] * len(vshape))
+    keys = idx.reshape(-1, _SCAN_ROW)
+    rows = _segmented_scan(keys, values.reshape(keys.shape + vshape), axis=1)
+    # what the rows before each row hold of the index that ends them
+    carry = _segmented_scan(keys[:, -1], rows[:, -1], axis=0)
+    into = _shift_back(keys[:, -1], 1, 0, -1)[:, None] == keys
+    extra = (None,) * len(vshape)
+    rows = rows + jnp.where(into[(Ellipsis,) + extra], _shift_back(carry, 1, 0, 0)[:, None], 0)
+    run_end = jnp.concatenate([idx[1:] != idx[:-1], jnp.ones((1,), bool)])
+    ends = jnp.where(run_end[(slice(None),) + extra], rows.reshape(values.shape), 0)
+    # sorted-ok: idx was sorted above, or the caller promised it (presorted)
+    return out.at[idx].add(ends, indices_are_sorted=True)
+
+
 def bin_read_reduce(
     bins: Bins,
     out_size: int,
@@ -277,6 +358,8 @@ def bin_read_reduce(
 
     def one(v: jnp.ndarray) -> jnp.ndarray:
         dt = jnp.dtype(out_dtype or v.dtype)
+        if op == "add" and jnp.issubdtype(dt, jnp.inexact):
+            return pairwise_segment_sum(bins.idx, v.astype(dt), out_size, presorted=sw <= 1)
         if op == "add":
             out = jnp.zeros((out_size,) + v.shape[1:], dtype=dt)
             return out.at[bins.idx].add(v.astype(dt), indices_are_sorted=sw <= 1)

@@ -232,3 +232,26 @@ def test_sharded_csr_and_pagerank_compile_for_four_v5e(four_chips, compiled_kern
         assert "tpu_custom_call" in program, name
         assert "all-to-all" in program, name
     assert "all-to-all" in text(csr, *edges)
+
+
+def test_kron24_pagerank_fits_one_v5e(one_chip, tmp_path):
+    """``kron24-pagerank1``'s program: ``_pr_fused`` at the decision the
+    executor makes on a v5e for Graph500 scale 24 (16,777,216 vertices,
+    536,870,912 arcs), past the fused kernel's VMEM budget, compiles for
+    one chip within its 15.75 GiB of HBM. A later change to that decision,
+    or to the two-phase reduce it runs, that pushes the cell out of HBM
+    fails here and not on the chip."""
+    from repro.core.pagerank import _pr_fused
+
+    n, m = 1 << 24, 1 << 29
+    ex = _v5e_executor(tmp_path)
+    d = ex.decide(n, m, jnp.float32, kind="reduce")
+    assert d.method == "counting"
+
+    def fn(src, dst):
+        return _pr_fused(src, dst, n, 1, d.method, d.bin_range, d.num_bins, ex.block, d.plan)
+
+    args = [jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)] * 2
+    mem = jax.jit(fn).lower(*args).compile().memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert used <= int(15.75 * (1 << 30))
